@@ -4,7 +4,8 @@ Analytic part: E[replications of a data vertex] and E[largest partition]
 for 1D/2D/RVC/CRVC/InferSpark at the paper's regime (K=O(1) and K=O(M)),
 plus the per-iteration communication volume of each runtime layout.
 
-Measured part (subprocess, 8 fake devices): wall time per VMP iteration and
+Measured part (subprocess pinned to ``JAX_PLATFORMS=cpu``, 8 fake devices;
+a failed child fails the benchmark): wall time per VMP iteration and
 HLO collective bytes for the three runtime strategies — the TPU analogue of
 Figure 20 (tailor-made layout vs generic partitioner vs replicated), plus
 the Infer.NET-style replicated memory model (the paper's 512GB anecdote).
@@ -67,17 +68,17 @@ def run(report):
 
     # measured: the three runtime strategies on 8 fake devices
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
-    try:
-        out = subprocess.run([sys.executable, "-c", _MEASURE_SNIPPET],
-                             capture_output=True, text=True, timeout=1200,
-                             env=env)
-        for line in out.stdout.splitlines():
-            if line.startswith("MEASURE"):
-                _, strat, us = line.split()
-                report(f"partition_measured_{strat}", float(us),
-                       "devices=8;model=lda_16x2000")
-    except Exception as e:                            # pragma: no cover
-        report("partition_measured_error", 0.0, str(e)[:60])
+    out = subprocess.run([sys.executable, "-c", _MEASURE_SNIPPET],
+                         capture_output=True, text=True, timeout=1200,
+                         env=env)
+    if out.returncode != 0:
+        raise RuntimeError(f"partition child failed with rc "
+                           f"{out.returncode}:\n{out.stderr}")
+    for line in out.stdout.splitlines():
+        if line.startswith("MEASURE"):
+            _, strat, us = line.split()
+            report(f"partition_measured_{strat}", float(us),
+                   "devices=8;model=lda_16x2000")

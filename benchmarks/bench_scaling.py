@@ -2,7 +2,9 @@
 
 Scale-up runs LDA per-iteration time against growing corpora in-process.
 Scale-out launches subprocesses with 1/2/4/8 fake CPU devices (device count
-locks at first jax init) and measures the inferspark-strategy step time.
+locks at first jax init; ``JAX_PLATFORMS=cpu``, so a child never contends
+for a chip the parent holds) and measures the inferspark-strategy step time.
+A child that fails fails the benchmark.
 """
 
 from __future__ import annotations
@@ -56,18 +58,17 @@ def run(report):
 
     # Figure 19: scale-out (subprocesses, fake devices)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     for n_dev in (1, 2, 4, 8):
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c", _SCALE_OUT_SNIPPET, str(n_dev)],
-                capture_output=True, text=True, timeout=900, env=env)
-            line = [l for l in out.stdout.splitlines()
-                    if l.startswith("PER_ITER_US")]
-            us = float(line[0].split()[1]) if line else float("nan")
-        except Exception:
-            us = float("nan")
-        report(f"vmp_scaleout_{n_dev}dev", us,
+        out = subprocess.run(
+            [sys.executable, "-c", _SCALE_OUT_SNIPPET, str(n_dev)],
+            capture_output=True, text=True, timeout=900, env=env)
+        line = [l for l in out.stdout.splitlines()
+                if l.startswith("PER_ITER_US")]
+        if out.returncode != 0 or not line:
+            raise RuntimeError(f"scale-out child ({n_dev} devices) failed "
+                               f"with rc {out.returncode}:\n{out.stderr}")
+        report(f"vmp_scaleout_{n_dev}dev", float(line[0].split()[1]),
                "strategy=inferspark;note=fake_cpu_devices_1core")

@@ -36,9 +36,15 @@ Prints ``name,us_per_call,derived`` CSV.  Select modules with
 ``python -m benchmarks.run [vmp|scaling|partition|kernels] ...``.
 
 ``--json`` additionally writes one ``BENCH_<module>.json`` per selected
-module — ``{"module", "backend", "rows": [{"name", "us_per_call",
-"derived", ...}]}`` — the machine-readable perf trajectory CI uploads as an
-artifact so regressions are diffable across commits.
+module — ``{"module", "backend", "device", "rows": [{"name",
+"us_per_call", "derived", ...}]}`` — the machine-readable perf trajectory
+CI uploads as an artifact so regressions are diffable across commits.
+``device`` is the platform, kind and count jax reports for this process.
+
+The modules that measure fake CPU devices (scaling, partition, multihost)
+run them in child processes pinned to ``JAX_PLATFORMS=cpu``, so a parent
+that holds a TPU never shares it with a child; a child that fails fails
+the run.
 """
 
 from __future__ import annotations
@@ -62,11 +68,15 @@ def main() -> None:
     json_mode = "--json" in args
     picks = [a for a in args if a in mods] or list(mods)
 
-    try:
-        from repro.kernels.ops import _backend
-        backend = _backend()
-    except Exception:                 # pragma: no cover - kernels optional
-        backend = "unknown"
+    import jax
+
+    from repro import compile_cache
+    from repro.kernels.ops import _backend
+    compile_cache.enable()
+    backend = _backend()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": jax.device_count()}
 
     print("name,us_per_call,derived")
     for p in picks:
@@ -82,8 +92,8 @@ def main() -> None:
         if json_mode:
             path = f"BENCH_{p}.json"
             with open(path, "w") as fh:
-                json.dump({"module": p, "backend": backend, "rows": rows},
-                          fh, indent=1)
+                json.dump({"module": p, "backend": backend, "device": device,
+                           "rows": rows}, fh, indent=1)
             print(f"# wrote {path}", file=sys.stderr)
 
 

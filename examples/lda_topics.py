@@ -44,6 +44,8 @@ def main():
                          "artifact at DIR (docs/query_serving.md); "
                          "query it with examples/query_topics.py")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
 
     n_docs = max(10, args.words // 120)
     print(f"[lda] generating ~{args.words} words over {n_docs} docs ...")

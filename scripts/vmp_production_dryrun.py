@@ -18,7 +18,7 @@ from repro.core.partition import ShardingPlan, make_distributed_step
 from repro.data import SyntheticCorpus
 from repro.launch import hlo_cost
 from repro.launch import roofline as RL
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_KIND, make_production_mesh
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
 
@@ -50,7 +50,8 @@ def run(multi_pod: bool):
     roof = RL.roofline({"flops": parsed.flops,
                         "bytes accessed": parsed.traffic},
                        {"total_bytes": parsed.as_dict()["collective_bytes"]},
-                       n_chips, model_flops=mflops)
+                       n_chips, model_flops=mflops,
+                       device_kind=PRODUCTION_KIND)
     result = {
         "arch": "vmp-lda-96x9040", "shape": "paper_wiki",
         "mesh": "2x16x16" if multi_pod else "16x16",

@@ -1,41 +1,28 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""Mesh, shard_map and multi-process set-up, spelled once for the repo.
 
-The codebase targets the modern jax API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``check_vma``); older runtimes (0.4.x) spell
-these ``jax.experimental.shard_map.shard_map``, have no axis types, and
-call the replication check ``check_rep``.  Every mesh/shard_map construction
-in the repo goes through these two helpers so the rest of the code can be
-written against one API.
+Every mesh and shard_map in the repo goes through these helpers so each
+makes the same choices: ``Auto`` mesh axes (``jax.make_mesh`` defaults to
+``Explicit`` sharding, which the partitioned engines do not use), no
+replication check inside ``shard_map``, and gloo collectives for
+multi-process CPU runs.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the runtime supports
-    them (explicit-sharding-safe) and plain axes elsewhere."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (old),
-    with the replication/VMA check disabled under either spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the replication (VMA) check disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
@@ -48,13 +35,8 @@ def distributed_initialize(coordinator_address: str, num_processes: int,
     set *before* initialization, every collective (and even the implicit
     ``assert_equal`` inside multi-process ``device_put``) fails with
     "Multiprocess computations aren't implemented on the CPU backend".
-    Newer jax versions default to gloo and may drop the option, so a
-    missing config name is ignored.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - depends on installed jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -323,20 +323,25 @@ class _ShardParts:
 def _put_leaf(vv, mesh=None, axes=()):
     """One batch leaf onto the device(s): ``None`` passes through,
     :class:`_ShardParts` becomes a global leading-dim-sharded array over
-    ``mesh``/``axes`` (each process contributes its own shards' rows),
-    plain numpy becomes a local ``jnp`` array."""
+    ``mesh``/``axes`` (each process contributes its own shards' rows).
+    Plain numpy is split the same way when ``mesh`` is given, each shard's
+    rows copied straight to its device, and otherwise becomes a ``jnp``
+    array on the default device."""
     if vv is None:
         return None
     if isinstance(vv, _ShardParts):
         from repro.launch.shardings import shard_stacked_array
         return shard_stacked_array(mesh, axes, vv.shape, vv.dtype, vv.parts)
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(vv, NamedSharding(mesh, P(axes)))
     return jnp.asarray(vv)
 
 
 def device_put_batch(batch: dict, mesh=None, axes=()) -> dict:
     """Place a :func:`host_batch` result's numpy leaves on device
-    (``None`` leaves pass through).  ``mesh``/``axes`` serve the multi-host
-    path — see :func:`_put_leaf`."""
+    (``None`` leaves pass through).  ``mesh``/``axes`` shard the plan
+    path's leading shard dim over the mesh — see :func:`_put_leaf`."""
     return {"arrays": {k: {kk: _put_leaf(vv, mesh, axes)
                            for kk, vv in v.items()}
                        for k, v in batch["arrays"].items()},
@@ -857,8 +862,8 @@ class SVI:
         else:
             hb, caps, _, n_b = self._load_groups(self.sampler.batch_at(t))
         batch = device_put_batch(
-            hb, mesh=self.plan.mesh if self._multiproc else None,
-            axes=self.plan.axes if self._multiproc else ())
+            hb, mesh=self.plan.mesh if self.plan is not None else None,
+            axes=self.plan.axes if self.plan is not None else ())
         sig = tuple(sorted(caps.items()))
         if sig not in self._steps:
             self._steps[sig] = make_svi_step(
@@ -887,6 +892,12 @@ class SVI:
             return float("nan")
         if self.hosts is not None:
             return self._heldout_hosts(state)
+        if self.plan is not None:
+            # the scorer is a one-device program, and jit cannot partition
+            # its Pallas kernels over the mesh the replicated state sits on
+            dev = self.plan.mesh.devices.flat[0]
+            state = VMPState(jax.device_put(state.posteriors, dev),
+                             state.step)
         return heldout_elbo(self.program, state, self.holdout,
                             self.cfg.holdout_local_iters,
                             cache=self._heldout_cache, slicer=self._slicer)

@@ -22,9 +22,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _VMEM_BUDGET = 4 * 1024 * 1024        # bytes per input block
+# Scoped VMEM each kernel of this package may use.  Mosaic's default (16 MiB)
+# is too small for a double-buffered 8-row block of a 10^5-column table, or
+# for the fused kernels' streamed tiles plus their one-hot temporaries; v5e
+# has 128 MiB of VMEM per core.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=48 * 1024 * 1024)
 _LANE = 128
+_SUB = 8
 
 
 def _digamma(x: jax.Array) -> jax.Array:
@@ -55,7 +62,8 @@ def dirichlet_expectation(alpha: jax.Array, *, interpret: bool = False) -> jax.A
         raise ValueError("expected (rows, K)")
     g, k = alpha.shape
     kp = max(_LANE, (k + _LANE - 1) // _LANE * _LANE)
-    block_rows = max(1, min(512, _VMEM_BUDGET // (kp * 4)))
+    # whole (8, 128) tiles: a row block is a multiple of 8 sublanes
+    block_rows = max(_SUB, min(512, _VMEM_BUDGET // (kp * 4)) // _SUB * _SUB)
     gp = (g + block_rows - 1) // block_rows * block_rows
 
     a = jnp.pad(alpha.astype(jnp.float32),
@@ -66,6 +74,7 @@ def dirichlet_expectation(alpha: jax.Array, *, interpret: bool = False) -> jax.A
         in_specs=[pl.BlockSpec((block_rows, kp), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, kp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((gp, kp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(a)
     return out[:g, :k].astype(alpha.dtype)

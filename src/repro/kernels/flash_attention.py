@@ -66,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True) -> jax.Array:
+                    block_k: int = 256, interpret: bool = False) -> jax.Array:
     """q: (BH, Sq, Dh); k/v: (BH, Sk, Dh) — heads pre-flattened (GQA callers
     broadcast kv heads first).  Returns (BH, Sq, Dh).
 
@@ -86,7 +86,7 @@ def _flash_vjp(q, k, v, causal, block_q, block_k, interpret):
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret"))
 def _flash_fwd_impl(q, k, v, causal=True, block_q=256, block_k=256,
-                    interpret=True):
+                    interpret=False):
     if q.ndim != 3 or k.shape != v.shape or q.shape[0] != k.shape[0]:
         raise ValueError("expected (BH, S, Dh) operands")
     bh, sq, dh = q.shape

@@ -43,9 +43,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .dirichlet_expectation import COMPILER_PARAMS
 from .fused_zstats import (_LANE, _TABLE_BUDGET, _block_tokens,
-                           _child_message, _child_scatter, _elog_from_alpha,
-                           _layout, _onehot, _pad_to, _zstats_call)
+                           _child_message, _child_scatter, _dot,
+                           _elog_from_alpha, _layout, _onehot, _pad_to,
+                           _zstats_call)
 from .ref import ZChild
 
 
@@ -83,7 +85,8 @@ def fusable_zmap(table_prior, children, tables: str = "elog",
 
 
 def _pad_tok(a, np_, fill=0):
-    return jnp.pad(a, (0, np_ - a.shape[0]), constant_values=fill)
+    """A token stream padded to ``np_`` slots, as a (np_, 1) column."""
+    return jnp.pad(a, (0, np_ - a.shape[0]), constant_values=fill)[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +124,7 @@ def _logits_kernel(*refs, k: int, meta1: tuple, lane_pad: int, mode: str):
     e = _child_message(tab, vals, base, tm_ref[...], k, lane,
                        specialized, stride)
     oh_z = _onehot(zmi_ref[...], zacc_ref.shape[0])
-    zacc_ref[...] += jnp.dot(oh_z.T, e, preferred_element_type=jnp.float32)
+    zacc_ref[...] += _dot(oh_z.T, e)
 
 
 def _phase_inputs(c: ZChild, kp: int, nzp: int, cdim: tuple, tables: str,
@@ -154,7 +157,7 @@ def _phase_logits(c: ZChild, k: int, kp: int, nzp: int, cdim: tuple,
                                                  tables, block_n)
     np_ = vals.shape[0]
 
-    tok = pl.BlockSpec((bn,), lambda i: (i,))
+    tok = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     inputs = [tab, vals, zmi, tm]
     in_specs = [pl.BlockSpec((gfp, kfp), lambda i: (0, 0)), tok, tok, tok]
     if base is not None:
@@ -172,6 +175,7 @@ def _phase_logits(c: ZChild, k: int, kp: int, nzp: int, cdim: tuple,
         out_specs=pl.BlockSpec((nzp, kp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nzp, kp), jnp.float32),
         scratch_shapes=scratch_shapes,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -197,7 +201,7 @@ def _stats_kernel(*refs, k: int, meta1: tuple):
         cref[...] = jnp.zeros(cref.shape, cref.dtype)
 
     oh_z = _onehot(zmi_ref[...], r_ref.shape[0])
-    w = jnp.dot(oh_z, r_ref[...], preferred_element_type=jnp.float32)
+    w = _dot(oh_z, r_ref[...])
     base = None if base_ref is None else base_ref[...]
     cref[...] += _child_scatter(w, vals_ref[...], base, tm_ref[...],
                                 cref.shape, k, specialized, stride)
@@ -210,7 +214,7 @@ def _phase_stats(c: ZChild, r, k: int, kp: int, nzp: int, cdim: tuple,
                                                "elog", block_n)
     np_ = vals.shape[0]
 
-    tok = pl.BlockSpec((bn,), lambda i: (i,))
+    tok = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     inputs = [r, vals, zmi, tm]
     in_specs = [pl.BlockSpec((nzp, kp), lambda i: (0, 0)), tok, tok, tok]
     if base is not None:
@@ -224,6 +228,7 @@ def _phase_stats(c: ZChild, r, k: int, kp: int, nzp: int, cdim: tuple,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((gfp, kfp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((gfp, kfp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
     return out[:gf, :kf]

@@ -67,7 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dirichlet_expectation import _digamma
+from .dirichlet_expectation import COMPILER_PARAMS, _digamma
 from .ref import ZChild
 
 _VMEM_BUDGET = 2 * 1024 * 1024        # bytes for the largest per-block tensor
@@ -84,17 +84,27 @@ def _pad_to(x: int, m: int) -> int:
 
 def _block_tokens(block_n: Optional[int], *dims: int) -> int:
     """Tokens per grid block: the largest per-block (bn, max(dims)) f32
-    temporary must fit ``_VMEM_BUDGET``.  The one block-size formula for
-    every kernel in this package (flat, streamed, and the zmap phases)."""
+    temporary should fit ``_VMEM_BUDGET``.  A whole number of 128-token
+    lane tiles, since the scatters transpose the block and put its tokens
+    on the lane axis.  The one block-size formula for every kernel in this
+    package (flat, streamed, and the zmap phases)."""
     m = max(dims)
-    return block_n or max(_SUB, min(512, _VMEM_BUDGET // (4 * m)
-                                    // _SUB * _SUB))
+    return block_n or max(_LANE, min(512, _VMEM_BUDGET // (4 * m))
+                          // _LANE * _LANE)
 
 
 def _onehot(idx, width: int):
-    """(bn,) int32 -> (bn, width) f32 one-hot via 2-D iota (TPU-legal)."""
+    """(bn, 1) int32 -> (bn, width) f32 one-hot via 2-D iota (TPU-legal)."""
     cols = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], width), 1)
-    return (idx[:, None] == cols).astype(jnp.float32)
+    return (idx == cols).astype(jnp.float32)
+
+
+def _dot(a, b):
+    """f32 matmul at full precision.  The TPU's default f32 matmul rounds
+    its operands to bf16; a one-hot operand survives that exactly, but the
+    table or responsibilities on the other side would lose 16 bits."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -127,7 +137,7 @@ def rowsum_digamma(alpha: jax.Array) -> jax.Array:
 def _prior_block(ptab, rows, k: int):
     """Prior gather + padded-lane kill -> (oh_p, lane, logits)."""
     oh_p = _onehot(rows, ptab.shape[0])
-    logits = jnp.dot(oh_p, ptab, preferred_element_type=jnp.float32)
+    logits = _dot(oh_p, ptab)
     lane = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = logits + jnp.where(lane < k, 0.0, _NEG)
     return oh_p, lane, logits
@@ -138,27 +148,28 @@ def _child_message(tab, vals, base, mask, k: int, lane,
     """One child factor's Elog message rows for a token block -> (bn, kp)."""
     oh_v = _onehot(vals, tab.shape[1])
     if specialized:                                # row IS the topic
-        e = jnp.dot(oh_v, tab.T, preferred_element_type=jnp.float32)
+        e = _dot(oh_v, tab.T)
     else:                                          # row = base + stride*z
         b = base if base is not None else jnp.zeros_like(vals)
         e = jnp.zeros(lane.shape, jnp.float32)
         for kk in range(k):
             oh_r = _onehot(b + stride * kk, tab.shape[0])
-            g = jnp.dot(oh_r, tab, preferred_element_type=jnp.float32)
+            g = _dot(oh_r, tab)
             e = e + jnp.where(lane == kk,
-                              (g * oh_v).sum(-1)[:, None], 0.0)
+                              (g * oh_v).sum(-1, keepdims=True), 0.0)
     if mask is not None:
-        e = e * mask[:, None]
+        e = e * mask
     return e
 
 
 def _softmax_block(logits, zm):
-    """Masked softmax + summed logsumexp of one block -> (r, lse_sum)."""
+    """Masked softmax + summed logsumexp of one block -> (r, lse_sum).
+    ``zm`` is the (bn, 1) token validity column."""
     m = logits.max(axis=-1, keepdims=True)
     ex = jnp.exp(logits - m)
     s = ex.sum(axis=-1, keepdims=True)
-    r = ex / s * zm[:, None]
-    lse = jnp.sum((m[:, 0] + jnp.log(s[:, 0])) * zm)
+    r = ex / s * zm
+    lse = jnp.sum((m + jnp.log(s)) * zm)
     return r, lse
 
 
@@ -166,15 +177,14 @@ def _child_scatter(r, vals, base, mask, shape: tuple, k: int,
                    specialized: bool, stride: int):
     """Responsibility-weighted count scatter of one block -> ``shape``."""
     oh_v = _onehot(vals, shape[1])
-    w = r if mask is None else r * mask[:, None]
+    w = r if mask is None else r * mask
     if specialized:
-        return jnp.dot(w.T, oh_v, preferred_element_type=jnp.float32)
+        return _dot(w.T, oh_v)
     b = base if base is not None else jnp.zeros_like(vals)
     acc = jnp.zeros(shape, jnp.float32)
     for kk in range(k):
         oh_r = _onehot(b + stride * kk, shape[0])
-        acc = acc + jnp.dot(oh_r.T, oh_v * w[:, kk:kk + 1],
-                            preferred_element_type=jnp.float32)
+        acc = acc + _dot(oh_r.T, oh_v * w[:, kk:kk + 1])
     return acc
 
 
@@ -195,7 +205,7 @@ def _block_step(ptab, tabs, rows, vals, bases, masks, zm, k: int,
         logits = logits + _child_message(tab, v, b, mk, k, lane,
                                          specialized, stride)
     r, lse = _softmax_block(logits, zm)
-    pd = jnp.dot(oh_p.T, r, preferred_element_type=jnp.float32)
+    pd = _dot(oh_p.T, r)
     cds = [_child_scatter(r, v, b, mk, tab.shape, k, specialized, stride)
            for tab, v, b, mk, (specialized, stride, _, _) in
            zip(tabs, vals, bases, masks, meta)]
@@ -265,8 +275,8 @@ def _plan(table_prior, children, tables: str = "elog",
             return None
         target = big[0]
         if target == "prior":
-            tl = _TILE_BUDGET // (4 * kp) // _SUB * _SUB
-            if tl < _SUB:              # one row wider than a tile's budget
+            tl = _TILE_BUDGET // (4 * kp) // _LANE * _LANE
+            if tl < _LANE:             # 128 rows wider than a tile's budget
                 return None
             n_tiles = -(-gpp // tl)
             gpp = n_tiles * tl
@@ -483,7 +493,7 @@ def _kernel(*refs, plan: _Plan, meta: tuple, lane_pads: tuple,
     extra = None if extra_ref is None else extra_ref[...]
     lse, pd, cds, r = _block_step(ptab, tabs, rows, vals, bases, masks,
                                   zm_ref[...], plan.k, meta, extra)
-    lse_ref[0] = lse
+    lse_ref[...] = jnp.full(lse_ref.shape, lse, jnp.float32)
     pstats_ref[...] += pd
     for cref, cd in zip(cstat_refs, cds):
         cref[...] += cd
@@ -502,12 +512,12 @@ class _Layout(NamedTuple):
     meta: tuple                        # per child (spec, stride, base?, mask?)
     lane_pads: tuple                   # per table: lane padding count
     ptab: jax.Array                    # (gpp, kp) padded prior table
-    prow: jax.Array                    # (np_,) bucketed+padded prior rows
-    zm: jax.Array                      # (np_,) token validity
+    prow: jax.Array                    # (np_, 1) bucketed+padded prior rows
+    zm: jax.Array                      # (np_, 1) token validity
     ctabs: tuple                       # per child padded table
-    cvals: tuple                       # per child (np_,) values
-    cbases: tuple                      # per child (np_,) base or None
-    cmasks: tuple                      # per child (np_,) mask or None
+    cvals: tuple                       # per child (np_, 1) values
+    cbases: tuple                      # per child (np_, 1) base or None
+    cmasks: tuple                      # per child (np_, 1) mask or None
     dg0: Optional[jax.Array]           # (kp, 1) streamed-child rowsum digamma
     blk_tile: jax.Array                # (nblocks,) per-block tile index
     nblocks: int
@@ -560,7 +570,10 @@ def _layout(table_prior, prior_rows, children, zmask, *,
     srcc = jnp.clip(src, 0)
 
     def ptok(a, fill=0):
-        return jnp.where(src >= 0, a[srcc], fill)
+        """Token stream in slot order, as a (np_, 1) column: a lane-dense
+        (bn, 1) block per grid step (the TPU refuses rank-1 blocks that are
+        not whole 128-lane tiles)."""
+        return jnp.where(src >= 0, a[srcc], fill)[:, None]
 
     zm = jnp.ones((n,), jnp.float32) if zmask is None \
         else zmask.astype(jnp.float32)
@@ -599,13 +612,14 @@ def _zstats_call(lo: _Layout, extra=None, emit_r: bool = False,
     ``extra`` — optional ``(nblocks*bn, kp)`` pre-accumulated logits added
     after the prior gather (the zmap kernel's phase-one output); ``emit_r``
     appends the block responsibilities as a final ``(nblocks*bn, kp)``
-    output.  Returns the raw ``pallas_call`` outputs
-    ``[lse_blocks, pstats, *cstats, r?]`` (padded, unsliced).
+    output.  Returns the ``pallas_call`` outputs
+    ``[lse_blocks, pstats, *cstats, r?]`` (padded, unsliced; ``lse_blocks``
+    is the ``(nblocks,)`` per-block lse sums).
     """
     plan, bn = lo.plan, lo.plan.bn
     kp, gpp = plan.kp, plan.gpp
 
-    tok_spec = pl.BlockSpec((bn,), lambda i, bt: (i,))
+    tok_spec = pl.BlockSpec((bn, 1), lambda i, bt: (i, 0))
     inputs = [lo.ptab]
     if plan.target == "prior":
         in_specs = [pl.BlockSpec((plan.tl, kp), lambda i, bt: (bt[i], 0))]
@@ -636,9 +650,11 @@ def _zstats_call(lo: _Layout, extra=None, emit_r: bool = False,
         inputs.append(extra)
         in_specs.append(pl.BlockSpec((bn, kp), lambda i, bt: (i, 0)))
 
-    out_shape = [jax.ShapeDtypeStruct((lo.nblocks,), jnp.float32),
+    # each block's lse sum fills one (8, 128) tile: a lane-aligned block
+    out_shape = [jax.ShapeDtypeStruct((lo.nblocks * _SUB, _LANE),
+                                      jnp.float32),
                  jax.ShapeDtypeStruct((gpp, kp), jnp.float32)]
-    out_specs = [pl.BlockSpec((1,), lambda i, bt: (i,))]
+    out_specs = [pl.BlockSpec((_SUB, _LANE), lambda i, bt: (i, 0))]
     if plan.target == "prior":
         out_specs.append(pl.BlockSpec((plan.tl, kp),
                                       lambda i, bt: (bt[i], 0)))
@@ -672,14 +688,16 @@ def _zstats_call(lo: _Layout, extra=None, emit_r: bool = False,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_kernel, plan=plan, meta=lo.meta,
                           lane_pads=lo.lane_pads,
                           has_extra=extra is not None, emit_r=emit_r),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(lo.blk_tile, *inputs)
+    return [outs[0][::_SUB, 0], *outs[1:]]
 
 
 def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,

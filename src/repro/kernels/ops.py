@@ -24,12 +24,9 @@ from .vmp_zstep import zstep as _zstep_pallas
 def _backend_cached() -> str:
     if os.environ.get("REPRO_FORCE_PALLAS") == "1":
         return "pallas_interpret"
-    try:
-        if jax.default_backend() == "tpu":
-            return "pallas"
-    except Exception:  # pragma: no cover - device init failure
-        pass
-    return "ref"
+    # a device that fails to initialize raises here: answering "ref" would
+    # run the chip's work on the host's CPU without saying so
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def _backend() -> str:

@@ -404,8 +404,8 @@ def zstats_blocked(table_prior: jax.Array, prior_rows: jax.Array,
     kernels must match within float tolerance.  Lazily imports the shared
     layout/block helpers (pure jnp) from the kernel modules.
     """
-    from .fused_zstats import (_child_message, _child_scatter, _layout,
-                               _onehot)
+    from .fused_zstats import (_child_message, _child_scatter, _dot,
+                               _layout, _onehot)
     if not any(c.zmap is not None for c in children):
         lo = _layout(table_prior, prior_rows, children, zmask,
                      tables=tables, block_n=block_n)
@@ -437,8 +437,7 @@ def zstats_blocked(table_prior: jax.Array, prior_rows: jax.Array,
                                tm[sl], k, lane, c.specialized,
                                int(c.stride))
             oh_z = _onehot(zmi[sl], nzp)
-            zacc = zacc + jnp.dot(oh_z.T, e,
-                                  preferred_element_type=jnp.float32)
+            zacc = zacc + _dot(oh_z.T, e)
         extra = extra + zacc
 
     # phase 2a: latent-plate softmax + prior/non-zmap stats (+ r)
@@ -471,7 +470,7 @@ def zstats_blocked(table_prior: jax.Array, prior_rows: jax.Array,
         for b in range(vals.shape[0] // bn):
             sl = slice(b * bn, (b + 1) * bn)
             oh_z = _onehot(zmi[sl], nzp)
-            w = jnp.dot(oh_z, r, preferred_element_type=jnp.float32)
+            w = _dot(oh_z, r)
             acc = acc + _child_scatter(
                 w, vals[sl], None if base is None else base[sl],
                 tm[sl], acc.shape, k, c.specialized, int(c.stride))

@@ -43,7 +43,8 @@ def zstep(logits: jax.Array, *, interpret: bool = False):
         raise ValueError("expected (N, K)")
     n, k = logits.shape
     kp = max(_LANE, (k + _LANE - 1) // _LANE * _LANE)
-    block_n = max(1, min(1024, _VMEM_BUDGET // (kp * 4)))
+    # the (block_n,) lse block must be whole 128-lane tiles
+    block_n = max(_LANE, min(1024, _VMEM_BUDGET // (kp * 4)) // _LANE * _LANE)
     np_ = (n + block_n - 1) // block_n * block_n
 
     x = jnp.pad(logits.astype(jnp.float32), ((0, np_ - n), (0, kp - k)),

@@ -35,7 +35,7 @@ from repro.configs import ARCHS, SHAPES, RunConfig, cell_enabled, get_arch
 from repro.models import input_specs, make_model
 from repro.launch import hlo_cost
 from repro.launch import roofline as RL
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_KIND, make_production_mesh
 from repro.launch.steps import (build_decode_step, build_prefill_step,
                                 build_train_step, jit_decode_step,
                                 jit_prefill_step, jit_train_step)
@@ -116,7 +116,8 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     coll["total_bytes"] = parsed.as_dict()["collective_bytes"]
     corrected = {"flops": parsed.flops, "bytes accessed": parsed.traffic}
     roof = RL.roofline(corrected, {"total_bytes": coll["total_bytes"]},
-                       n_chips, model_flops=mflops)
+                       n_chips, model_flops=mflops,
+                       device_kind=PRODUCTION_KIND)
     roof["dynamic_loops_hinted"] = parsed.dynamic_loops
 
     result = {

@@ -18,6 +18,9 @@ import numpy as np
 
 from repro.compat import make_mesh
 
+# the chip the production meshes are made of (a ``launch.roofline.PEAKS`` key)
+PRODUCTION_KIND = "TPU v5 lite"
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

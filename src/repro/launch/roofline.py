@@ -7,17 +7,33 @@
 FLOPs/bytes come from ``compiled.cost_analysis()``.  Collective bytes are not
 in cost_analysis: we parse the post-SPMD HLO text and sum operand sizes of
 every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute.  Hardware constants: TPU v5e.
+collective-permute.  Hardware constants come from :data:`PEAKS`, keyed by the
+``device_kind`` jax reports; a kind missing from it is an error, never a
+default.
 """
 
 from __future__ import annotations
 
 import re
 
-# TPU v5e, per chip
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s per ICI link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" (TPU v5e): Google Cloud documentation, "TPU v5e" — 197
+# TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect
+# (four ICI links of 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Per-chip ``flops`` (bf16 FLOP/s), ``hbm_bw`` and ``link_bw``
+    (bytes/s) of ``device_kind``; raises ``KeyError`` for a kind with no
+    published entry in :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -74,20 +90,21 @@ def collective_stats(hlo_text: str) -> dict:
 
 
 def roofline(cost: dict, coll: dict, n_chips: int, model_flops: float = 0.0,
-             per_device_cost: bool = True) -> dict:
-    """The three terms in seconds + bottleneck.
+             per_device_cost: bool = True, *, device_kind: str) -> dict:
+    """The three terms in seconds + bottleneck, at ``device_kind``'s peaks.
 
     ``cost_analysis`` on an SPMD executable reports per-device numbers
     (the module is the per-device program); set per_device_cost=False if the
     numbers are whole-program.
     """
+    pk = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     bytes_ = float(cost.get("bytes accessed", 0.0))
     cbytes = float(coll.get("total_bytes", 0))
     div = 1.0 if per_device_cost else float(n_chips)
-    t_compute = flops / div / PEAK_FLOPS
-    t_memory = bytes_ / div / HBM_BW
-    t_coll = cbytes / LINK_BW        # HLO collective shapes are per-device
+    t_compute = flops / div / pk["flops"]
+    t_memory = bytes_ / div / pk["hbm_bw"]
+    t_coll = cbytes / pk["link_bw"]  # HLO collective shapes are per-device
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     bottleneck = max(terms, key=terms.get)
@@ -103,7 +120,7 @@ def roofline(cost: dict, coll: dict, n_chips: int, model_flops: float = 0.0,
         # roofline fraction: useful model FLOPs over the time the dominant
         # term implies at peak
         t_dom = max(terms.values())
-        out["roofline_fraction"] = (model_flops / n_chips / PEAK_FLOPS) \
+        out["roofline_fraction"] = (model_flops / n_chips / pk["flops"]) \
             / max(t_dom, 1e-30)
     return out
 

@@ -416,12 +416,12 @@ def test_svi_validate_kwarg():
 @contextlib.contextmanager
 def _forbid_tracing(monkeypatch):
     """Fail the test if any jax primitive binds (tracing or device op)."""
-    import jax
+    from jax.extend.core import Primitive
 
     def _no_bind(self, *a, **k):
         raise AssertionError(
             f"static analysis bound jax primitive {self!r}")
-    monkeypatch.setattr(jax.core.Primitive, "bind", _no_bind)
+    monkeypatch.setattr(Primitive, "bind", _no_bind)
     yield
 
 

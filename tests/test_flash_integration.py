@@ -36,7 +36,8 @@ def test_flash_kernel_differentiable():
     q, k, v = (jnp.asarray(rng.normal(size=(2, 32, 16)).astype(np.float32))
                for _ in range(3))
     gk = jax.grad(lambda *a: flash_attention(
-        *a, causal=True, block_q=16, block_k=16).sum(), argnums=(0, 1, 2))(
+        *a, causal=True, block_q=16, block_k=16, interpret=True).sum(),
+        argnums=(0, 1, 2))(
         q, k, v)
     gr = jax.grad(lambda *a: ref.flash_attention(*a, causal=True).sum(),
                   argnums=(0, 1, 2))(q, k, v)

@@ -106,3 +106,15 @@ def test_shape_bytes():
     assert hlo_cost._shape_bytes("bf16[4]") == 8
     assert hlo_cost._shape_bytes("(f32[2,2], s32[3])") == 16 + 12
     assert hlo_cost._shape_bytes("pred[7]") == 7
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch import roofline
+    cost = {"flops": 197e12, "bytes accessed": 819e9 / 2}
+    out = roofline.roofline(cost, {"total_bytes": 0}, 1,
+                            device_kind="TPU v5 lite")
+    assert out["compute_s"] == pytest.approx(1.0)
+    assert out["memory_s"] == pytest.approx(0.5)
+    assert out["bottleneck"] == "compute"
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.roofline(cost, {"total_bytes": 0}, 1, device_kind="cpu")
