@@ -49,6 +49,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from . import dists
 from .compiler import (VMPProgram, local_dirichlets, slice_arrays,
@@ -158,10 +159,12 @@ def make_svi_step(program: VMPProgram, caps: dict[str, int], plan=None,
         sliced = {}
         for name, d in program.dirichlets.items():
             if name in local:
-                rows = batch["dirs"][name]["rows"]
-                mask = batch["dirs"][name]["mask"]
-                got = state.posteriors[name][jnp.clip(rows, 0, d.g - 1)]
-                sliced[name] = jnp.where(mask[:, None] > 0, got, priors[name])
+                with jax.named_scope("svi.local_rows"):
+                    rows = batch["dirs"][name]["rows"]
+                    mask = batch["dirs"][name]["mask"]
+                    got = state.posteriors[name][jnp.clip(rows, 0, d.g - 1)]
+                    sliced[name] = jnp.where(mask[:, None] > 0, got,
+                                             priors[name])
             else:
                 sliced[name] = state.posteriors[name]
 
@@ -179,35 +182,44 @@ def make_svi_step(program: VMPProgram, caps: dict[str, int], plan=None,
         posts = {}
         for name, d in program.dirichlets.items():
             if name in local:
-                rows = batch["dirs"][name]["rows"]
-                upd = new.posteriors[name]
-                if axes:
-                    # shards own disjoint rows; merge deltas, stay replicated
-                    delta = jnp.zeros_like(state.posteriors[name]).at[rows] \
-                        .add(upd - sliced[name], mode="drop")
-                    posts[name] = state.posteriors[name] + \
-                        jax.lax.psum(delta, axes)
-                else:
-                    posts[name] = state.posteriors[name].at[rows] \
-                        .set(upd, mode="drop")
+                with jax.named_scope("svi.local_rows"):
+                    rows = batch["dirs"][name]["rows"]
+                    upd = new.posteriors[name]
+                    if axes:
+                        # shards own disjoint rows; merge deltas, stay
+                        # replicated
+                        delta = jnp.zeros_like(state.posteriors[name]) \
+                            .at[rows].add(upd - sliced[name], mode="drop")
+                        posts[name] = state.posteriors[name] + \
+                            jax.lax.psum(delta, axes)
+                    else:
+                        posts[name] = state.posteriors[name].at[rows] \
+                            .set(upd, mode="drop")
             else:
                 # natural gradient: target = prior + scale * stats_B; the
                 # where()s keep the |B|=G, rho=1 case bitwise equal to the
                 # full-batch VMP update (no x-p+p float round-trip)
-                target = priors[name] + scale * \
-                    (new.posteriors[name] - priors[name])
-                target = jnp.where(scale == 1.0, new.posteriors[name], target)
-                blend = (1.0 - rho) * state.posteriors[name] + rho * target
-                posts[name] = jnp.where(rho == 1.0, target, blend)
+                with jax.named_scope("svi.global_update"):
+                    target = priors[name] + scale * \
+                        (new.posteriors[name] - priors[name])
+                    target = jnp.where(scale == 1.0, new.posteriors[name],
+                                       target)
+                    blend = (1.0 - rho) * state.posteriors[name] + \
+                        rho * target
+                    posts[name] = jnp.where(rho == 1.0, target, blend)
         return VMPState(posts, state.step + 1), elbo
 
+    # both forms jit a function named svi_step: a profile names the
+    # program after it (jit_svi_step)
     if plan is None:
-        return jax.jit(body, donate_argnums=(0,) if donate else ())
+        def svi_step(state, batch, rho, scale):
+            return body(state, batch, rho, scale)
+        return jax.jit(svi_step, donate_argnums=(0,) if donate else ())
 
     from jax.sharding import PartitionSpec as P
     from repro.compat import shard_map
 
-    def sharded_body(state, batch, rho, scale):
+    def svi_step(state, batch, rho, scale):
         sq = {"arrays": {k: {kk: (None if vv is None else vv[0])
                              for kk, vv in v.items()}
                          for k, v in batch["arrays"].items()},
@@ -226,7 +238,7 @@ def make_svi_step(program: VMPProgram, caps: dict[str, int], plan=None,
         arr_spec[s.x_name] = {"rows": P(axes), "values": P(axes),
                               "mask": P(axes)}
     dir_spec = {n: {"rows": P(axes), "mask": P(axes)} for n in local}
-    sharded = shard_map(sharded_body, plan.mesh,
+    sharded = shard_map(svi_step, plan.mesh,
                         in_specs=(state_spec,
                                   {"arrays": arr_spec, "dirs": dir_spec},
                                   P(), P()),
@@ -426,18 +438,20 @@ def build_local_scorer(program: VMPProgram, caps: dict[str, int],
                     priors[name], posteriors[name])
         return elbo
 
+    # both builds jit a function named local_score (the held-out scorer
+    # and fold-in): a profile names the program after it
     if not extras:
         @jax.jit
-        def fn(posteriors, arrays):
+        def local_score(posteriors, arrays):
             st, elbo = _fit_locals(_local_init(posteriors), arrays)
             return _drop_global_kl(elbo, posteriors)
 
-        return fn
+        return local_score
 
     from .vmp import _messages_to_latent
 
     @jax.jit
-    def fn_extras(posteriors, arrays, seg):
+    def local_score(posteriors, arrays, seg):
         st, elbo = _fit_locals(_local_init(posteriors), arrays)
         elbo = _drop_global_kl(elbo, posteriors)
 
@@ -471,7 +485,7 @@ def build_local_scorer(program: VMPProgram, caps: dict[str, int],
                                             num_segments=n_seg)
         return elbo, {n: st.posteriors[n] for n in local}, grp
 
-    return fn_extras
+    return local_score
 
 
 def _build_heldout_fn(program: VMPProgram, caps: dict[str, int],
@@ -537,20 +551,25 @@ def heldout_elbo(program: VMPProgram, state: VMPState, groups,
     groups = np.asarray(groups, np.int64)
     if slicer is None:
         slicer = lambda g, cf: slice_arrays(program, g, cf)  # noqa: E731
-    arrays, dirs, caps, n_tok = slicer(groups, None)
+    with TraceAnnotation("svi.heldout.slice"):
+        arrays, dirs, caps, n_tok = slicer(groups, None)
     if n_tok == 0:
         return float("nan")
-    fn = None
-    sig = (tuple(sorted(caps.items())), inner_iters)
-    if cache is not None:
-        fn = cache.get(sig)
-    if fn is None:
-        fn = _build_heldout_fn(program, caps, inner_iters)
+    with TraceAnnotation("svi.heldout.put"):
+        dev = {k: {kk: None if vv is None else jnp.asarray(vv)
+                   for kk, vv in v.items()} for k, v in arrays.items()}
+    with TraceAnnotation("svi.heldout.dispatch"):
+        fn = None
+        sig = (tuple(sorted(caps.items())), inner_iters)
         if cache is not None:
-            cache[sig] = fn
-    dev = {k: {kk: None if vv is None else jnp.asarray(vv)
-               for kk, vv in v.items()} for k, v in arrays.items()}
-    return float(fn(state.posteriors, dev)) / n_tok
+            fn = cache.get(sig)
+        if fn is None:
+            fn = _build_heldout_fn(program, caps, inner_iters)
+            if cache is not None:
+                cache[sig] = fn
+        score = fn(state.posteriors, dev)
+    with TraceAnnotation("svi.heldout.sync"):
+        return float(score) / n_tok
 
 
 # ---------------------------------------------------------------------------
@@ -856,35 +875,47 @@ class SVI:
             replicated_array(mesh, np.asarray(state.step, np.int32)))
 
     def step(self, t: int, state: VMPState):
-        """One SVI step at schedule position ``t``; returns (state', elbo)."""
-        if self.corpus is not None:
-            hb, caps, _, n_b = self.sampler.host_batch_at(t)
-        else:
-            hb, caps, _, n_b = self._load_groups(self.sampler.batch_at(t))
-        batch = device_put_batch(
-            hb, mesh=self.plan.mesh if self.plan is not None else None,
-            axes=self.plan.axes if self.plan is not None else ())
-        sig = tuple(sorted(caps.items()))
-        if sig not in self._steps:
-            self._steps[sig] = make_svi_step(
-                self.program, caps, plan=self.plan,
-                local_iters=self.cfg.local_iters,
-                elog_dtype=self.cfg.elog_dtype)
-        rho = (self.cfg.rho if self.cfg.rho is not None
-               else robbins_monro(t, self.cfg.tau, self.cfg.kappa))
-        # n_b is the true batch size (the epoch's tail batch may be short).
-        # The stochastic scale G/|B|: G is the training population — fixed
-        # in batch mode, the epoch snapshot size under a growing corpus,
-        # or a pinned assumed population (population-VI) for unbounded
-        # streams.  Traced as a scalar either way: growth never retraces.
-        if self.cfg.growing:
-            n_pop = (self.cfg.population_size
-                     or self.sampler.population_at(t))
-        else:
-            n_pop = len(self.train)
-        scale = n_pop / n_b
-        return self._steps[sig](state, batch, self._scalar(rho),
-                                self._scalar(scale))
+        """One SVI step at schedule position ``t``; returns (state', elbo).
+
+        Host spans (profiler trace only): ``svi.host_batch``,
+        ``svi.device_put``, ``svi.dispatch`` (``svi.compile`` inside it on
+        a new step signature, which also holds that first call)."""
+        with TraceAnnotation("svi.host_batch"):
+            if self.corpus is not None:
+                hb, caps, _, n_b = self.sampler.host_batch_at(t)
+            else:
+                hb, caps, _, n_b = self._load_groups(
+                    self.sampler.batch_at(t))
+        with TraceAnnotation("svi.device_put"):
+            batch = device_put_batch(
+                hb, mesh=self.plan.mesh if self.plan is not None else None,
+                axes=self.plan.axes if self.plan is not None else ())
+        with TraceAnnotation("svi.dispatch"):
+            rho = (self.cfg.rho if self.cfg.rho is not None
+                   else robbins_monro(t, self.cfg.tau, self.cfg.kappa))
+            # n_b is the true batch size (the epoch's tail batch may be
+            # short).  The stochastic scale G/|B|: G is the training
+            # population — fixed in batch mode, the epoch snapshot size
+            # under a growing corpus, or a pinned assumed population
+            # (population-VI) for unbounded streams.  Traced as a scalar
+            # either way: growth never retraces.
+            if self.cfg.growing:
+                n_pop = (self.cfg.population_size
+                         or self.sampler.population_at(t))
+            else:
+                n_pop = len(self.train)
+            args = (state, batch, self._scalar(rho),
+                    self._scalar(n_pop / n_b))
+            sig = tuple(sorted(caps.items()))
+            fn = self._steps.get(sig)
+            if fn is None:
+                with TraceAnnotation("svi.compile"):
+                    fn = self._steps[sig] = make_svi_step(
+                        self.program, caps, plan=self.plan,
+                        local_iters=self.cfg.local_iters,
+                        elog_dtype=self.cfg.elog_dtype)
+                    return fn(*args)
+            return fn(*args)
 
     def heldout_elbo(self, state: VMPState) -> float:
         """Per-token held-out ELBO at ``state`` (NaN without a holdout)."""
@@ -1028,6 +1059,10 @@ class SVI:
         ``resume_from=True`` with no session yet is a cold start, so the
         always-on loop can use one code path.  ``steps`` counts the
         updates *this call* runs (on resume: the remaining budget).
+
+        Under a profiler session each iteration is a ``svi.step`` step
+        span holding ``svi.elbo_sync`` and ``svi.heldout`` (see "Tracing a
+        fit" in ``docs/inference_engines.md``).
         """
         from repro.checkpoint import CheckpointStore
         from repro.checkpoint import session as _session
@@ -1070,21 +1105,26 @@ class SVI:
         start = int(state.step)
         try:
             for t in range(start, start + steps):
-                faults.trip("svi.step")
-                state, elbo = self.step(t, state)
-                elbo_f = float(elbo)
-                history["elbo"].append(elbo_f)
-                if (len(self.holdout) and self.cfg.holdout_every
-                        and ((t + 1) % self.cfg.holdout_every == 0
-                             or t == start + steps - 1)):
-                    history["heldout"].append((t, self.heldout_elbo(state)))
-                if store is not None and (
-                        (t + 1) % store.every == 0 or t == start + steps - 1):
-                    _session.save_session(
-                        store, self._snapshot_session(state, history),
-                        force=True)
-                if callback is not None and callback(t, elbo_f) is False:
-                    break
+                with StepTraceAnnotation("svi.step", step_num=t):
+                    faults.trip("svi.step")
+                    state, elbo = self.step(t, state)
+                    with TraceAnnotation("svi.elbo_sync"):
+                        elbo_f = float(elbo)
+                    history["elbo"].append(elbo_f)
+                    if (len(self.holdout) and self.cfg.holdout_every
+                            and ((t + 1) % self.cfg.holdout_every == 0
+                                 or t == start + steps - 1)):
+                        with TraceAnnotation("svi.heldout"):
+                            held = self.heldout_elbo(state)
+                        history["heldout"].append((t, held))
+                    if store is not None and (
+                            (t + 1) % store.every == 0
+                            or t == start + steps - 1):
+                        _session.save_session(
+                            store, self._snapshot_session(state, history),
+                            force=True)
+                    if callback is not None and callback(t, elbo_f) is False:
+                        break
         finally:
             if store is not None:
                 store.wait()

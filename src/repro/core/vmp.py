@@ -193,8 +193,9 @@ def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
     new_posts = {}
     for name, d in program.dirichlets.items():
         prior = jnp.asarray(d.prior)[None, :]
-        term = dists.dirichlet_elbo_term(prior, state.posteriors[name],
-                                         selog.get(name))
+        with jax.named_scope("vmp.elbo"):
+            term = dists.dirichlet_elbo_term(prior, state.posteriors[name],
+                                             selog.get(name))
         st = stats[name]
         if axis_names and name not in local_dirs:
             st = jax.lax.psum(st, axis_names)

@@ -45,6 +45,7 @@ import threading
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.testing import faults
 
@@ -1094,7 +1095,8 @@ class ShardedMinibatchSampler:
         self._inner.restore_epochs(records)
 
     def _load_at(self, step: int):
-        batch = self.loader(self.batch_at(step))
+        with TraceAnnotation("store.load_groups", step=step):
+            batch = self.loader(self.batch_at(step))
         nbytes = _tree_nbytes(batch)
         # double-buffered: the previous batch is still live at the consumer
         # while this one builds; without prefetch only one batch is ever
@@ -1107,12 +1109,15 @@ class ShardedMinibatchSampler:
 
     def host_batch_at(self, step: int):
         """``loader(batch_at(step))``, prefetched: the call for ``step+1``
-        starts on the worker thread before this one returns."""
+        starts on the worker thread before this one returns.  In a profiler
+        trace the caller's wait is a ``store.batch_wait`` span and each
+        load a ``store.load_groups`` span (with its ``step``)."""
         if self.loader is None:
             raise ValueError("no loader bound; use batch_at()")
         if self._prefetcher is None:
             return self._load_at(step)
-        return self._prefetcher.get(step)
+        with TraceAnnotation("store.batch_wait"):
+            return self._prefetcher.get(step)
 
     def close(self, timeout: Optional[float] = 5.0) -> bool:
         """Stop the prefetch worker (idempotent).  Joins with ``timeout``

@@ -241,7 +241,16 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
         latent whose tables exceed VMEM) falls back to the chunked ``ref``
         oracle, which streams token chunks through a ``lax.scan`` and so
         also never materializes the (N_token, K) working set.
+
+    Every route runs under the ``kernels.zstats`` named scope, so its
+    device ops carry that name in a profile.
     """
+    with jax.named_scope("kernels.zstats"):
+        return _zstats(table_prior, prior_rows, children, zmask, tables,
+                       bucketing)
+
+
+def _zstats(table_prior, prior_rows, children, zmask, tables, bucketing):
     b = _backend()
     if b != "ref":
         interp = b == "pallas_interpret"
