@@ -296,53 +296,103 @@ def _plan(table_prior, children, tables: str = "elog",
                  bn, tables)
 
 
-def _bucket(key, n: int, tl: int, n_tiles: int, bn: int):
+def _bucket(key, n: int, tl: int, n_tiles: int, bn: int, streams=None):
     """Bucket tokens by streamed-table tile, padding each bucket to whole
     ``bn`` blocks (at least one per tile, so every accumulator tile is
     visited and flushed).  Pure trace-time jnp: returns ``(src, slot_tile,
-    blk_tile)`` where ``src`` maps padded slots to source tokens (-1 =
-    padding), over the static padded length ``(ceil(n/bn) + n_tiles)*bn``.
+    blk_tile, placed)`` where ``src`` maps padded slots to source tokens
+    (-1 = padding), over the static padded length
+    ``(ceil(n/bn) + n_tiles)*bn``, and ``placed`` is ``streams`` (an
+    ``(R, n)`` int32 stack of token streams) in slot order, with
+    unspecified values in the padding slots.
+
+    Every bucket starts on a block boundary, so the tiles are found per
+    block, not per slot, and block ``b``'s slots hold a run of ``bn``
+    consecutive tokens of the stable sort by tile.  The sort carries the
+    streams, so one gather of ``nblocks`` runs (:func:`_runs`) places all
+    of them; the tile counts are the sorted tile ids' ``n_tiles + 1``
+    boundaries.  The searches are unrolled (no device loop): ``log2`` of
+    their sorted operand steps over ``n_tiles + 1`` and ``nblocks``
+    queries.
     """
     tid = (key.astype(jnp.int32) // tl).astype(jnp.int32)
-    order = jnp.argsort(tid)                       # stable
-    cnt = jnp.bincount(tid, length=n_tiles)
-    pcnt = jnp.maximum(-(-cnt // bn), 1) * bn
-    cum_p = jnp.cumsum(pcnt)
-    off = cum_p - pcnt                             # padded bucket starts
-    cstart = jnp.cumsum(cnt) - cnt                 # sorted bucket starts
-    tid_s = tid[order]
-    pos = off[tid_s] + (jnp.arange(n) - cstart[tid_s])
-    np_ = (-(-n // bn) + n_tiles) * bn
-    src = jnp.full((np_,), -1, jnp.int32).at[pos].set(order.astype(jnp.int32))
-    slot_tile = jnp.clip(jnp.searchsorted(cum_p, jnp.arange(np_),
-                                          side="right"),
-                         0, n_tiles - 1).astype(jnp.int32)
-    return src, slot_tile, slot_tile[::bn]
+    if streams is None:
+        streams = jnp.zeros((0, n), jnp.int32)
+    tid_s, order, *cols = jax.lax.sort(
+        (tid, jnp.arange(n, dtype=jnp.int32), *streams), num_keys=1,
+        is_stable=True)
+    nblocks = -(-n // bn) + n_tiles
+    bounds = jnp.searchsorted(tid_s, jnp.arange(n_tiles + 1, dtype=jnp.int32),
+                              method="scan_unrolled").astype(jnp.int32)
+    cstart = bounds[:-1]                           # sorted bucket starts
+    cnt = bounds[1:] - cstart
+    pblk = jnp.maximum(-(-cnt // bn), 1)           # blocks per bucket
+    cum_b = jnp.cumsum(pblk)
+    blk = jnp.arange(nblocks, dtype=jnp.int32)
+    blk_tile = jnp.minimum(
+        jnp.searchsorted(cum_b, blk, side="right", method="scan_unrolled"),
+        n_tiles - 1).astype(jnp.int32)
+    first = (cum_b - pblk)[blk_tile] * bn          # padded bucket starts
+    start = cstart[blk_tile] + blk * bn - first    # sorted token of slot b*bn
+    end = first + cnt[blk_tile]                    # its bucket's first pad
+    placed = _runs(jnp.stack([order, *cols]), start, bn)
+    slot = jnp.arange(nblocks * bn, dtype=jnp.int32)
+    src = jnp.where(slot < jnp.repeat(end, bn), placed[0], -1)
+    return src, jnp.repeat(blk_tile, bn), blk_tile, placed[1:]
+
+
+def _runs(x, start, width: int):
+    """``x[:, s:s + width]`` for every ``s`` in ``start``, side by side:
+    ``(R, len(start) * width)``; a run past the end of ``x`` holds
+    unspecified values.  Runs at unaligned lane offsets would compile to
+    a device loop with one trip per run, so this gathers whole 128-lane
+    rows along the untiled leading axis (one gather) and then shifts
+    each window left by ``s % 128`` lanes in seven static steps."""
+    r, n = x.shape
+    w = -(-width // _LANE) + 1                     # rows a run can touch
+    rows = -(-n // _LANE) + w
+    x = jnp.pad(x, ((0, 0), (0, rows * _LANE - n)))
+    x = x.reshape(r, rows, _LANE).transpose(1, 0, 2)
+    win = jax.vmap(lambda q: jax.lax.dynamic_slice_in_dim(x, q, w))(
+        start // _LANE)
+    win = win.transpose(0, 2, 1, 3).reshape(start.shape[0], r, w * _LANE)
+    shift = start % _LANE
+    for k in range(7):                             # 2**7 == _LANE
+        step = 1 << k
+        rolled = jnp.concatenate([win[..., step:], win[..., :step]], -1)
+        win = jnp.where((shift >> k & 1)[:, None, None] == 1, rolled, win)
+    return win[..., :width].transpose(1, 0, 2).reshape(r, -1)
 
 
 def _bucket_host(key: np.ndarray, n: int, tl: int, n_tiles: int, bn: int):
-    """Numpy twin of :func:`_bucket`, op-for-op (stable sort, identical
-    padding arithmetic), so a bucketing computed once on the host is
-    bitwise the one the traced version would produce.  The permutation
-    depends only on the observed values, so for a fixed program it never
-    changes — computing it here keeps the argsort out of the jitted step
-    (where the traced version re-sorts on device every iteration)."""
+    """Numpy twin of :func:`_bucket`'s ``(src, slot_tile, blk_tile)``,
+    op-for-op (stable sort, identical padding arithmetic; the runs by
+    plain indexing), so a bucketing computed once on the host is bitwise
+    the one the traced version would produce.  The permutation depends
+    only on the observed values, so for a fixed program it never changes
+    — computing it here keeps the sort out of the jitted step (where the
+    traced version re-sorts on device every iteration)."""
     tid = (key.astype(np.int64) // tl).astype(np.int32)
-    order = np.argsort(tid, kind="stable")
-    cnt = np.bincount(tid, minlength=n_tiles)
-    pcnt = np.maximum(-(-cnt // bn), 1) * bn
-    cum_p = np.cumsum(pcnt)
-    off = cum_p - pcnt
-    cstart = np.cumsum(cnt) - cnt
+    order = np.argsort(tid, kind="stable").astype(np.int32)
     tid_s = tid[order]
-    pos = off[tid_s] + (np.arange(n) - cstart[tid_s])
-    np_ = (-(-n // bn) + n_tiles) * bn
-    src = np.full((np_,), -1, np.int32)
-    src[pos] = order.astype(np.int32)
-    slot_tile = np.clip(np.searchsorted(cum_p, np.arange(np_),
-                                        side="right"),
-                        0, n_tiles - 1).astype(np.int32)
-    return src, slot_tile, slot_tile[::bn].copy()
+    nblocks = -(-n // bn) + n_tiles
+    bounds = np.searchsorted(tid_s, np.arange(n_tiles + 1))
+    cstart = bounds[:-1]
+    cnt = bounds[1:] - cstart
+    pblk = np.maximum(-(-cnt // bn), 1)
+    cum_b = np.cumsum(pblk)
+    blk = np.arange(nblocks)
+    blk_tile = np.minimum(np.searchsorted(cum_b, blk, side="right"),
+                          n_tiles - 1).astype(np.int32)
+    first = (cum_b - pblk)[blk_tile] * bn
+    start = cstart[blk_tile] + blk * bn - first
+    end = first + cnt[blk_tile]
+    # a run may read past the end only in padding slots
+    runs = np.concatenate([order, np.zeros(bn, np.int32)])
+    placed = runs[np.minimum(start, n)[:, None] + np.arange(bn)].reshape(-1)
+    slot = np.arange(nblocks * bn)
+    src = np.where(slot < np.repeat(end, bn), placed, -1).astype(np.int32)
+    return src, np.repeat(blk_tile, bn), blk_tile
 
 
 def host_bucketing(table_prior, prior_rows, children, *,
@@ -523,6 +573,7 @@ class _Layout(NamedTuple):
     nblocks: int
 
 
+@jax.named_scope("kernels.zstats.layout")
 def _layout(table_prior, prior_rows, children, zmask, *,
             tables: str = "elog", block_n: Optional[int] = None,
             bucketing=None) -> _Layout:
@@ -539,59 +590,78 @@ def _layout(table_prior, prior_rows, children, zmask, *,
         return jnp.pad(t, ((0, rows - t.shape[0]), (0, cols - t.shape[1])),
                        constant_values=jnp.asarray(fill, t.dtype))
 
+    # every token stream the kernel reads, one int32 row each (f32 streams
+    # as their bit patterns), so that one operation places them all
+    def bits(a):
+        return jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.int32)
+
+    zm = jnp.ones((n,), jnp.float32) if zmask is None else zmask
+    streams = [prior_rows.astype(jnp.int32), bits(zm)]
+    for c in children:
+        streams.append(c.values.astype(jnp.int32))
+        if c.base is not None:
+            streams.append(c.base.astype(jnp.int32))
+        if c.mask is not None:
+            streams.append(bits(c.mask))
+    streams = jnp.stack(streams)
+
     key = None
     if plan.target == "prior":
         key = prior_rows
     elif plan.target is not None:
         key = children[plan.target].values
     if key is None:
+        # resident: the tokens in order, then zero padding (every stream's
+        # fill) to whole blocks
         np_ = _pad_to(max(n, 1), bn)
-        src = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
-                               jnp.full((np_ - n,), -1, jnp.int32)])
-        slot_tile = jnp.zeros((np_,), jnp.int32)
+        placed = jnp.pad(streams, ((0, 0), (0, np_ - n)))
+        pad = slot_tile = None
         blk_tile = jnp.zeros((np_ // bn,), jnp.int32)
-    elif bucketing is not None:
-        # host-precomputed permutation (see host_bucketing): enters the
-        # trace as constants, so the per-step device argsort disappears
-        src, slot_tile, blk_tile = (jnp.asarray(b, jnp.int32)
-                                    for b in bucketing)
-        np_ = src.shape[0]
-        expect = (-(-n // bn) + plan.n_tiles) * bn
-        if np_ != expect:
-            raise ValueError(
-                f"stale bucketing: {np_} padded slots for a layout that "
-                f"needs {expect} (n={n}, bn={bn}, tiles={plan.n_tiles}) — "
-                f"recompute host_bucketing for this program")
     else:
-        src, slot_tile, blk_tile = _bucket(key.astype(jnp.int32), n,
-                                           plan.tl, plan.n_tiles, bn)
+        if bucketing is not None:
+            # host-precomputed permutation (see host_bucketing): enters the
+            # trace as constants, so the per-step device sort disappears
+            src, slot_tile, blk_tile = (jnp.asarray(b, jnp.int32)
+                                        for b in bucketing)
+            expect = (-(-n // bn) + plan.n_tiles) * bn
+            if src.shape[0] != expect:
+                raise ValueError(
+                    f"stale bucketing: {src.shape[0]} padded slots for a "
+                    f"layout that needs {expect} (n={n}, bn={bn}, "
+                    f"tiles={plan.n_tiles}) — recompute host_bucketing "
+                    f"for this program")
+            placed = streams[:, jnp.clip(src, 0)]
+        else:
+            src, slot_tile, blk_tile, placed = _bucket(
+                key.astype(jnp.int32), n, plan.tl, plan.n_tiles, bn,
+                streams)
         np_ = src.shape[0]
+        pad = src < 0
+    rows = iter(placed)
 
-    srcc = jnp.clip(src, 0)
+    def ptok(fill=0, f32=False):
+        """The next token stream in slot order, as a (np_, 1) column: a
+        lane-dense (bn, 1) block per grid step (the TPU refuses rank-1
+        blocks that are not whole 128-lane tiles).  ``fill`` goes in the
+        padding slots."""
+        a = next(rows)
+        if pad is not None:
+            a = jnp.where(pad, fill, a)
+        if f32:
+            a = jax.lax.bitcast_convert_type(a, jnp.float32)
+        return a[:, None]
 
-    def ptok(a, fill=0):
-        """Token stream in slot order, as a (np_, 1) column: a lane-dense
-        (bn, 1) block per grid step (the TPU refuses rank-1 blocks that are
-        not whole 128-lane tiles)."""
-        return jnp.where(src >= 0, a[srcc], fill)[:, None]
-
-    zm = jnp.ones((n,), jnp.float32) if zmask is None \
-        else zmask.astype(jnp.float32)
-    prow = prior_rows.astype(jnp.int32)
-    prow = ptok(prow, slot_tile * plan.tl if plan.target == "prior" else 0)
-
+    prow = ptok(slot_tile * plan.tl if plan.target == "prior" else 0)
+    zm = ptok(f32=True)
     lane_pads = [plan.kp - plan.k]
     ctabs, cvals, cbases, cmasks, meta = [], [], [], [], []
     dg0 = None
     for ci, (c, (gf, kf, gfp, kfp)) in enumerate(zip(children,
                                                      plan.child_dims)):
         ctabs.append(pad_table(c.elog, gfp, kfp))
-        fillv = slot_tile * plan.tl if plan.target == ci else 0
-        cvals.append(ptok(c.values.astype(jnp.int32), fillv))
-        cbases.append(None if c.base is None
-                      else ptok(c.base.astype(jnp.int32), 0))
-        cmasks.append(None if c.mask is None
-                      else ptok(c.mask.astype(jnp.float32), 0.0))
+        cvals.append(ptok(slot_tile * plan.tl if plan.target == ci else 0))
+        cbases.append(None if c.base is None else ptok())
+        cmasks.append(None if c.mask is None else ptok(f32=True))
         meta.append((c.specialized, int(c.stride),
                      c.base is not None, c.mask is not None))
         lane_pads.append(kfp - kf)
@@ -600,7 +670,7 @@ def _layout(table_prior, prior_rows, children, zmask, *,
             dg0 = jnp.pad(d, (0, plan.kp - d.shape[0]))[:, None]
     return _Layout(plan, tuple(meta), tuple(lane_pads),
                    pad_table(table_prior, plan.gpp, plan.kp),
-                   prow, ptok(zm, 0.0), tuple(ctabs), tuple(cvals),
+                   prow, zm, tuple(ctabs), tuple(cvals),
                    tuple(cbases), tuple(cmasks), dg0, blk_tile,
                    np_ // bn)
 

@@ -605,3 +605,239 @@ def test_full_batch_step_hoists_bucketing(monkeypatch):
     assert np.array_equal(np.sort(src[src >= 0]), np.arange(3000))
     for p in state.posteriors.values():
         assert np.isfinite(np.asarray(p)).all()
+
+
+# ---------------------------------------------------------------------------
+# the streamed path's slot layout, against numpy
+# ---------------------------------------------------------------------------
+
+def _layout_case(target, n, tables, *, zmask=True, extra_child=False,
+                 keys=None, seed=0):
+    """A zstats call whose ``target`` table ("prior", "child" or None) is
+    streamed; ``keys`` overrides the streamed index stream (prior rows or
+    the streamed child's values); ``zmask`` gives the latent and the first
+    child a mask; ``extra_child`` adds a strided child with a base and a
+    mask."""
+    rng = np.random.default_rng(seed)
+    k, gp, kf = {"prior": (16, 70000, 33), "child": (4, 11, 33000),
+                 None: (4, 20, 33)}[target]
+
+    def tab(shape):
+        return jnp.asarray((rng.gamma(1.0, 1.0, shape) + 1e-2)
+                           .astype(np.float32))
+
+    rows = rng.integers(0, gp, n).astype(np.int32)
+    vals = rng.integers(0, kf, n).astype(np.int32)
+    if keys is not None:
+        if target == "prior":
+            rows = keys.astype(np.int32)
+        else:
+            vals = keys.astype(np.int32)
+    children = [ref.ZChild(tab((k, kf)), jnp.asarray(vals), 1,
+                           mask=jnp.asarray((rng.random(n) > 0.25)
+                                            .astype(np.float32))
+                           if zmask else None)]
+    if extra_child:
+        stride = 3
+        gf = stride * k + 8
+        base = rng.integers(0, gf - stride * (k - 1), n).astype(np.int32)
+        children.append(ref.ZChild(
+            tab((gf, 9)), jnp.asarray(rng.integers(0, 9, n).astype(np.int32)),
+            stride, base=jnp.asarray(base),
+            mask=jnp.asarray((rng.random(n) > 0.5).astype(np.float32))))
+    zm = jnp.asarray((rng.random(n) > 0.15).astype(np.float32)) \
+        if zmask else None
+    return tab((gp, k)), jnp.asarray(rows), tuple(children), zm
+
+
+def _numpy_layout(et, rows, children, zmask, plan, tables):
+    """The layout's arrays built in numpy from ``_bucket_host`` (streamed)
+    or the tokens in order (resident), by plain indexing."""
+    from repro.kernels import fused_zstats as fz
+    n, bn = rows.shape[0], plan.bn
+    if plan.target is None:
+        np_ = -(-max(n, 1) // bn) * bn
+        src = np.concatenate([np.arange(n), np.full(np_ - n, -1)])
+        slot_tile = np.zeros(np_, np.int32)
+        blk_tile = np.zeros(np_ // bn, np.int32)
+    else:
+        key = rows if plan.target == "prior" else \
+            children[plan.target].values
+        src, slot_tile, blk_tile = fz._bucket_host(
+            np.asarray(key), n, plan.tl, plan.n_tiles, bn)
+
+    def ptok(a, dtype, fill=0):
+        a = np.asarray(a).astype(dtype)
+        return np.where(src >= 0, a[np.clip(src, 0, None)], fill)[:, None] \
+            .astype(dtype)
+
+    def pad(t, r, c):
+        t = np.asarray(t)
+        return np.pad(t, ((0, r - t.shape[0]), (0, c - t.shape[1])),
+                      constant_values=1.0 if tables == "alpha" else 0.0)
+
+    tfill = slot_tile * plan.tl
+    zm = np.ones(n, np.float32) if zmask is None else zmask
+    out = {"prow": ptok(rows, np.int32,
+                        tfill if plan.target == "prior" else 0),
+           "zm": ptok(zm, np.float32), "blk_tile": blk_tile,
+           "ptab": pad(et, plan.gpp, plan.kp), "cvals": [], "cbases": [],
+           "cmasks": [], "ctabs": [], "dg0": None}
+    for ci, (c, (_, _, gfp, kfp)) in enumerate(zip(children,
+                                                   plan.child_dims)):
+        out["ctabs"].append(pad(c.elog, gfp, kfp))
+        if tables == "alpha" and plan.target == ci:
+            d = np.asarray(fz.rowsum_digamma(c.elog))
+            out["dg0"] = np.pad(d, (0, plan.kp - d.shape[0]))[:, None]
+        out["cvals"].append(ptok(c.values, np.int32,
+                                 tfill if plan.target == ci else 0))
+        out["cbases"].append(None if c.base is None
+                             else ptok(c.base, np.int32))
+        out["cmasks"].append(None if c.mask is None
+                             else ptok(c.mask, np.float32))
+    return out
+
+
+def _assert_layout_equal(lo, want):
+    def same(a, b):
+        if b is None:
+            assert a is None
+            return
+        a = np.asarray(a)
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.atleast_1d(a).view(np.uint8),
+                              np.atleast_1d(b).view(np.uint8))
+
+    for f in ("prow", "zm", "blk_tile", "ptab", "dg0"):
+        same(getattr(lo, f), want[f])
+    for f in ("cvals", "cbases", "cmasks", "ctabs"):
+        assert len(getattr(lo, f)) == len(want[f])
+        for a, b in zip(getattr(lo, f), want[f]):
+            same(a, b)
+    assert lo.nblocks * lo.plan.bn == lo.prow.shape[0]
+
+
+def _tile_keys(counts, tl, hi, seed=0):
+    """A streamed index stream of values below ``hi`` with ``counts[t]``
+    tokens in tile ``t``, in shuffled order."""
+    rng = np.random.default_rng(seed)
+    keys = np.concatenate([rng.integers(t * tl, min((t + 1) * tl, hi), c)
+                           for t, c in enumerate(counts)])
+    return rng.permutation(keys)
+
+
+# the streamed child's tiles are 2,048 values (17 tiles), the prior's
+# 2,048 rows (35 tiles); block_n=128 keeps several blocks in a tile
+LAYOUT_CASES = {
+    "prior-elog": dict(target="prior", n=3000, tables="elog"),
+    "prior-alpha-base-no-zmask": dict(target="prior", n=2500,
+                                      tables="alpha", zmask=False,
+                                      extra_child=True),
+    "child-elog-no-masks": dict(target="child", n=3000, tables="elog",
+                                zmask=False),
+    "child-alpha-base-masks": dict(target="child", n=2000, tables="alpha",
+                                   extra_child=True),
+    "child-empty-tiles": dict(target="child", n=700, tables="elog",
+                              counts=[300, 0, 0, 250, 0, 0, 0, 0, 0, 150]),
+    "child-exact-multiples": dict(target="child", n=896, tables="alpha",
+                                  counts=[128, 0, 256, 384, 0, 0, 0, 0, 0, 0,
+                                          0, 0, 0, 0, 0, 0, 128]),
+    "child-single-tile": dict(target="child", n=1000, tables="alpha",
+                              counts=[0] * 16 + [1000]),
+    "prior-single-tile-one-token": dict(target="prior", n=1, tables="elog",
+                                        counts=[0, 0, 1]),
+    "resident-elog-base-masks": dict(target=None, n=300, tables="elog",
+                                     extra_child=True),
+    "resident-alpha-no-masks": dict(target=None, n=257, tables="alpha",
+                                    zmask=False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUT_CASES))
+def test_layout_matches_numpy_bitwise(name):
+    """Every field of the token layout the kernel reads equals, bitwise,
+    one built in numpy from the host bucketing by plain indexing — for
+    the in-trace layout and for the hoisted (``bucketing=``) one."""
+    from repro.kernels import fused_zstats as fz
+    spec = dict(LAYOUT_CASES[name])
+    counts = spec.pop("counts", None)
+    tables = spec["tables"]
+    if counts is not None:
+        hi = 70000 if spec["target"] == "prior" else 33000
+        spec["keys"] = _tile_keys(counts, 2048, hi)
+        assert len(spec["keys"]) == spec["n"]
+    et, rows, children, zm = _layout_case(**spec)
+    lo = fz._layout(et, rows, children, zm, tables=tables, block_n=128)
+    want_target = {"prior": "prior", "child": 0, None: None}[spec["target"]]
+    assert lo.plan.target == want_target
+    if counts is not None:
+        assert lo.plan.tl == 2048
+    want = _numpy_layout(et, rows, children,
+                         None if zm is None else np.asarray(zm),
+                         lo.plan, tables)
+    _assert_layout_equal(lo, want)
+    hb = fz.host_bucketing(et, rows, children, tables=tables, block_n=128)
+    assert (hb is None) == (lo.plan.target is None)
+    if hb is not None:
+        _assert_layout_equal(
+            fz._layout(et, rows, children, zm, tables=tables, block_n=128,
+                       bucketing=hb), want)
+
+
+def _token_axis_ops(jaxpr, n: int) -> dict:
+    """Counts of device loops (``while``, and ``scan`` not fully
+    unrolled), of gathers whose result has at least ``n`` elements, and
+    of scatters and sorts with an operand or result that large, in a
+    jaxpr and every jaxpr it calls."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    got = {"loop": 0, "gather": 0, "placement": 0}
+
+    def size(v):
+        return int(np.prod(v.aval.shape))
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            name = eqn.primitive.name
+            out = max(size(v) for v in eqn.outvars)
+            big = max([out] + [size(v) for v in eqn.invars
+                               if hasattr(v, "aval")]) >= n
+            if name == "while" or (name == "scan" and eqn.params["unroll"]
+                                   not in (True, eqn.params["length"])):
+                got["loop"] += 1
+            elif name == "gather" and out >= n:
+                got["gather"] += 1
+            elif (name == "sort" or name.startswith("scatter")) and big:
+                got["placement"] += 1
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    if isinstance(sub, ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        walk(sub)
+    walk(jaxpr.jaxpr)
+    return got
+
+
+def test_streamed_layout_has_no_loop_and_one_placement():
+    """The streamed layout at the NYTimes SVI step's shapes (171,505
+    tokens, V=102,660, K=256, 101 tiles): no device loop (the tiles are
+    found per block, not by a per-slot binary search), one sort that
+    carries the token streams and one gather over the token axis."""
+    import jax
+    from repro.kernels import fused_zstats as fz
+    n, v, k, docs = 171_505, 102_660, 256, 512
+    f32, i32 = jnp.float32, jnp.int32
+
+    def layout(prior, rows, phi, words, zm, mask):
+        lo = fz._layout(prior, rows, (ref.ZChild(phi, words, 1, mask=mask),),
+                        zm, tables="alpha")
+        assert lo.plan.target == 0 and lo.plan.n_tiles == 101
+        return lo.prow, lo.zm, lo.cvals, lo.cmasks, lo.blk_tile
+
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in
+              [((docs, k), f32), ((n,), i32), ((k, v), f32), ((n,), i32),
+               ((n,), f32), ((n,), f32)]]
+    got = _token_axis_ops(jax.make_jaxpr(layout)(*shapes), n)
+    assert got == {"loop": 0, "gather": 1, "placement": 1}
+    assert "while" not in jax.jit(layout).lower(*shapes).as_text()
