@@ -10,7 +10,11 @@ Plans — no tracing, no device work — for the same SVI configuration over:
     the chunked oracle.
 
 Same budget, different structure, different kernel.  The plan says so
-before the first step compiles::
+before the first step compiles.  The ``ref`` route of a segment latent at
+a large vocabulary is measured on the chip: the benchmark cell
+``train.slda-nytimes`` runs SLDA at V=102,660 and K=256 over a sharded
+corpus, on the route this plan predicts (``bench/train_plan.py`` prints
+it)::
 
     PYTHONPATH=src python examples/explain_plan.py [--docs 2000] [--json]
 """
@@ -51,7 +55,9 @@ def main():
     print(f"summary: lda routes {lda_r.path} "
           f"({lda_r.table_bytes / 2**20:.1f}MiB resident vs "
           f"{lda_r.budget / 2**20:.0f}MiB budget) while slda routes "
-          f"{slda_r.path} ({slda_r.table_bytes / 2**20:.1f}MiB)")
+          f"{slda_r.path} ({slda_r.table_bytes / 2**20:.1f}MiB); SLDA's "
+          f"ref route at the NYTimes widths is measured on the chip in the "
+          f"benchmark cell train.slda-nytimes")
 
 
 if __name__ == "__main__":

@@ -162,12 +162,14 @@ def _fmt(b: int) -> str:
 # caps prediction: replay the real sampler + the real slicer, in numpy
 # ---------------------------------------------------------------------------
 
-def _svi_caps(program, cfg):
+def _svi_caps(program, cfg, corpus=None):
     """The exact cap signature ``SVI.step(0)`` will trace at: the same
     holdout split, the same ``batch_at(0)``, the same ``slice_arrays``
-    padding — all the actual code, none of it traced."""
+    padding (``slice_sharded`` over a sharded ``corpus``) — all the actual
+    code, none of it traced."""
     from repro.core.compiler import slice_arrays
     from repro.data.pipeline import MinibatchSampler, holdout_split
+    from repro.data.store import slice_sharded
 
     n_groups = program.meta["pstar_size"]
     if cfg.holdout_frac > 0:
@@ -182,8 +184,12 @@ def _svi_caps(program, cfg):
         m = cfg.pad_multiple
         return n if not m else -(-max(n, 1) // m) * m
 
-    arrays, dirs, caps, n_tokens = slice_arrays(
-        program, sampler.batch_at(0), caps_fn)
+    if corpus is None:
+        arrays, dirs, caps, n_tokens = slice_arrays(
+            program, sampler.batch_at(0), caps_fn)
+    else:
+        arrays, dirs, caps, n_tokens = slice_sharded(
+            program, corpus, sampler.batch_at(0), caps_fn)
     batch_bytes = sum(a.nbytes for d in arrays.values()
                       for a in d.values() if a is not None)
     batch_bytes += sum(a.nbytes for d in dirs.values() for a in d.values())
@@ -272,7 +278,11 @@ def explain_plan(model, config=None, *, corpus=None, backend=None,
     numpy).  ``config`` — ``SVIConfig`` (minibatch plan), ``EngineConfig``
     (engine chosen by its ``backend`` field), or ``None`` (full-batch
     VMP).  ``corpus`` — optional ``ShardedCorpus`` for working-set and
-    host-partition context.  ``backend`` — plan for a specific kernel
+    host-partition context; with a model that observes nothing, the plan
+    is of SVI over that corpus (the program ``SVI(model, config,
+    corpus=corpus)`` builds with ``sharded_template``, its first batch
+    sliced by ``slice_sharded``, a sentence latent's axis in the notes).
+    ``backend`` — plan for a specific kernel
     backend (``"pallas"`` to plan for TPU from anywhere); default is this
     process's dispatch answer.  ``n_hosts`` — include the multi-host
     partition summary.
@@ -295,7 +305,13 @@ def explain_plan(model, config=None, *, corpus=None, backend=None,
                          "the fold-in scorer's (zstats) view")
 
     b = backend if backend is not None else _backend()
+    sharded = corpus is not None and not getattr(model, "observations", {})
+    if sharded and svi_cfg is None:
+        raise ValueError("a sharded corpus is trained by SVI; pass an "
+                         "SVIConfig (or an EngineConfig with backend='svi')")
     diags = validate_model(model)
+    if sharded:                     # the corpus is what the model observes
+        diags = [d for d in diags if d.code != "no-observed"]
     name = getattr(getattr(model, "net", model), "name", "?")
     plan = Plan(model=name, engine=engine, backend=b, tables="alpha",
                 diagnostics=diags, caps=None, signature=None, routes=[],
@@ -303,17 +319,30 @@ def explain_plan(model, config=None, *, corpus=None, backend=None,
     if any(d.severity == "error" for d in diags):
         return plan
 
-    program = model.compile()
+    if sharded:
+        from repro.data.store import sharded_template
+        program = sharded_template(model, corpus)
+    else:
+        program = model.compile()
     if svi_cfg is not None:
         if program.meta.get("pstar") is None:
             plan.notes.append("model has no '?' partition plate; SVI "
                               "unavailable — planning full batch instead")
             svi_cfg = None
     if svi_cfg is not None:
-        caps, batch_bytes, _ = _svi_caps(program, svi_cfg)
+        caps, batch_bytes, _ = _svi_caps(program, svi_cfg,
+                                         corpus if sharded else None)
         plan.caps = dict(caps)
         plan.signature = tuple(sorted(caps.items()))
         plan.routes = _routes(program, caps, True, b, "alpha", elog_dtype)
+        for spec in program.latents:
+            if any(f.zmap is not None for f in spec.children):
+                plate = program.net.rvs[spec.name].plate.name
+                plan.notes.append(
+                    f"sentence axis: latent {spec.name} on plate {plate}, "
+                    f"{spec.n} instances in all, {caps[spec.name]} a step "
+                    f"(padded); each token's messages are summed into its "
+                    f"instance before the softmax")
     else:
         caps, batch_bytes = _full_caps(program)
         plan.caps = dict(caps)
@@ -419,14 +448,9 @@ def _main(argv=None) -> int:
         from repro.core import models
         from repro.data.store import ShardedCorpus
         corpus = ShardedCorpus.open(args.corpus_dir)
+        # unobserved: the plan is of SVI over the corpus itself
         m = models.make(args.model, alpha=0.1, beta=0.05, K=args.topics,
                         V=int(corpus.vocab))
-        lengths = np.asarray(corpus.lengths, np.int64)
-        doc_of_tok = np.repeat(np.arange(len(lengths), dtype=np.int32),
-                               lengths)
-        # extents (not values) drive the plan: zeros stand in for tokens
-        m["x"].observe(np.zeros(int(lengths.sum()), np.int32),
-                       segment_ids=doc_of_tok)
     else:
         m = synthesize_model(args.model, docs=args.docs, vocab=args.vocab,
                              topics=args.topics, mean_len=args.mean_len)

@@ -9,7 +9,10 @@ module keeps the corpus on disk instead:
   ``manifest.json`` of per-shard group (document) offsets and vocab stats,
   and a small resident ``lengths.npy`` (``(n_docs,) int64``, the only
   O(n_docs) state).  Shards are split on document boundaries, so a document
-  minibatch touches only the shards its documents live in.
+  minibatch touches only the shards its documents live in.  A corpus
+  written with sentence structure also keeps ``doc_sents.npy`` (each
+  document's sentence count) and ``sent_lengths.npy`` (each sentence's
+  token count), which segment-latent models such as SLDA slice by.
 - :class:`ShardedCorpusWriter` / :func:`write_sharded_corpus` — convert a
   :class:`~repro.data.pipeline.SyntheticCorpus` result (or any
   ``tokens``/``doc_ids`` numpy pair) to shards; the writer appends document
@@ -53,6 +56,8 @@ from .pipeline import MinibatchSampler, SyntheticCorpus
 
 _MANIFEST = "manifest.json"
 _LENGTHS = "lengths.npy"
+_DOC_SENTS = "doc_sents.npy"
+_SENT_LENGTHS = "sent_lengths.npy"
 _FORMAT = "sharded-corpus"
 _VERSION = 1
 _OWNER_TAG = 0x1f5c  # domain-separates ownership hashing from sampler seeds
@@ -147,7 +152,8 @@ class ShardedCorpusWriter:
 
     Call :meth:`add_docs` with ``(tokens, lengths)`` chunks — ``tokens`` a
     ``(sum lengths,) int`` array of the chunk's documents back to back,
-    ``lengths`` their ``(n_chunk_docs,) int`` token counts — then
+    ``lengths`` their ``(n_chunk_docs,) int`` token counts, and, for a
+    corpus with sentence structure, ``sent_lengths``/``doc_sents`` — then
     :meth:`close`.  A shard file is flushed whenever the buffered token
     count reaches ``shard_tokens`` (always on a document boundary, so one
     document never spans shards unless it alone exceeds ``shard_tokens``,
@@ -181,10 +187,22 @@ class ShardedCorpusWriter:
         self._token_max = -1
         self._commits = 0
         self._closed = False
+        # sentence structure: None until the first chunk says whether the
+        # corpus has it; then every chunk must agree
+        self._sentences: Optional[bool] = None
+        self._doc_sents: list[np.ndarray] = []
+        self._sent_lengths: list[np.ndarray] = []
         os.makedirs(self.path, exist_ok=True)
 
-    def add_docs(self, tokens, lengths) -> "ShardedCorpusWriter":
-        """Append one chunk of whole documents (see class docstring)."""
+    def add_docs(self, tokens, lengths, sent_lengths=None,
+                 doc_sents=None) -> "ShardedCorpusWriter":
+        """Append one chunk of whole documents (see class docstring).
+
+        ``sent_lengths`` — ``(n_chunk_sents,) int`` token counts of the
+        chunk's sentences, in document order; ``doc_sents`` —
+        ``(n_chunk_docs,) int`` sentence count of each document.  Give both
+        or neither, in every chunk alike; each document's sentences must
+        hold exactly its tokens."""
         if self._closed:
             raise RuntimeError("writer is closed")
         tokens = np.ascontiguousarray(tokens, np.int32).ravel()
@@ -196,6 +214,15 @@ class ShardedCorpusWriter:
                              f"has {len(tokens)} tokens")
         if len(tokens) and int(tokens.min()) < 0:
             raise ValueError("negative token id")
+        sents = _check_sentences(lengths, sent_lengths, doc_sents)
+        if self._sentences is None:
+            self._sentences = sents is not None
+        elif self._sentences != (sents is not None):
+            raise ValueError("every chunk of a corpus gives sentence lengths, "
+                             "or none does")
+        if sents is not None:
+            self._doc_sents.append(sents[0])
+            self._sent_lengths.append(sents[1])
         if len(tokens):
             self._token_max = max(self._token_max, int(tokens.max()))
         self._n_docs += len(lengths)
@@ -296,10 +323,23 @@ class ShardedCorpusWriter:
         with open(ltmp, "wb") as fh:
             np.save(fh, lengths)
         os.replace(ltmp, os.path.join(self.path, _LENGTHS))
+        extra = {}
+        if self._sentences:
+            # the same prefix rule as lengths: replaced before the manifest
+            doc_sents = np.concatenate(self._doc_sents)
+            sent_lengths = np.concatenate(self._sent_lengths)
+            for name, arr in ((_DOC_SENTS, doc_sents),
+                              (_SENT_LENGTHS, sent_lengths)):
+                tmp = os.path.join(self.path, name + ".tmp")
+                with open(tmp, "wb") as fh:
+                    np.save(fh, arr)
+                os.replace(tmp, os.path.join(self.path, name))
+            extra["n_sents"] = len(sent_lengths)
         faults.trip("store.commit.pre_manifest")
         manifest = {"format": _FORMAT, "version": _VERSION,
                     "commit": self._commits,
                     "n_docs": self._n_docs, "n_tokens": self._n_tokens,
+                    **extra,
                     "vocab": vocab, "dtype": "int32",
                     "shards": self._shards,
                     # writer-recovery context (readers ignore it): the raw
@@ -403,6 +443,10 @@ class ShardedCorpusWriter:
                     f"tokens, manifest says {want}")
             if want:
                 legacy_max = max(legacy_max, int(s["token_max"]))
+        sents = _load_sentences(path, manifest)
+        if sents is not None:
+            w._doc_sents, w._sent_lengths = [sents[0]], [sents[1]]
+        w._sentences = sents is not None if n_docs else None
         w._shards = list(manifest["shards"])
         w._done_lengths = [lengths] if n_docs else []
         w._n_docs = n_docs
@@ -414,6 +458,57 @@ class ShardedCorpusWriter:
         return w
 
 
+def _check_sentences(lengths: np.ndarray, sent_lengths, doc_sents):
+    """``(doc_sents, sent_lengths)`` as int64 arrays after checking that
+    they split every document's tokens into its sentences; ``None`` when
+    neither is given."""
+    if sent_lengths is None and doc_sents is None:
+        return None
+    if sent_lengths is None or doc_sents is None:
+        raise ValueError("give both sent_lengths and doc_sents, or neither")
+    sent_lengths = np.asarray(sent_lengths, np.int64).ravel()
+    doc_sents = np.asarray(doc_sents, np.int64).ravel()
+    if len(doc_sents) != len(lengths):
+        raise ValueError(f"doc_sents has {len(doc_sents)} entries for "
+                         f"{len(lengths)} documents")
+    if (doc_sents < 0).any() or (sent_lengths < 0).any():
+        raise ValueError("negative sentence count or length")
+    if int(doc_sents.sum()) != len(sent_lengths):
+        raise ValueError(f"doc_sents sum to {int(doc_sents.sum())} but "
+                         f"{len(sent_lengths)} sentence lengths are given")
+    tok = np.concatenate([[0], np.cumsum(sent_lengths)])
+    first = np.concatenate([[0], np.cumsum(doc_sents)])
+    per_doc = tok[first[1:]] - tok[first[:-1]]
+    if not np.array_equal(per_doc, lengths):
+        bad = int(np.flatnonzero(per_doc != lengths)[0])
+        raise ValueError(f"document {bad}'s sentences hold "
+                         f"{int(per_doc[bad])} tokens, its length is "
+                         f"{int(lengths[bad])}")
+    return doc_sents, sent_lengths
+
+
+def _load_sentences(path: str, manifest: dict):
+    """The committed ``(doc_sents, sent_lengths)`` prefixes of a store
+    written with sentence structure (``None`` for one without: every
+    manifest written before sentences existed)."""
+    if "n_sents" not in manifest:
+        return None
+    n_docs, n_sents = int(manifest["n_docs"]), int(manifest["n_sents"])
+    doc_sents = np.load(os.path.join(str(path), _DOC_SENTS))
+    sent_lengths = np.load(os.path.join(str(path), _SENT_LENGTHS))
+    if len(doc_sents) < n_docs or len(sent_lengths) < n_sents:
+        raise ValueError(f"{path}: sentence files are shorter than the "
+                         f"manifest's {n_docs} documents and {n_sents} "
+                         f"sentences (torn commit?)")
+    doc_sents = np.asarray(doc_sents[:n_docs], np.int64)
+    sent_lengths = np.asarray(sent_lengths[:n_sents], np.int64)
+    if (int(doc_sents.sum()) != n_sents
+            or int(sent_lengths.sum()) != int(manifest["n_tokens"])):
+        raise ValueError(f"{path}: sentence files disagree with the "
+                         f"manifest's counts")
+    return doc_sents, sent_lengths
+
+
 def write_sharded_corpus(corpus, path: str, shard_tokens: int = 1 << 22,
                          vocab: Optional[int] = None) -> "ShardedCorpus":
     """One-shot conversion of a resident corpus to the sharded format.
@@ -423,6 +518,8 @@ def write_sharded_corpus(corpus, path: str, shard_tokens: int = 1 << 22,
     ``tokens`` (``(N,) int``) plus either ``lengths`` (``(n_docs,) int``)
     or ``doc_ids`` (``(N,) int``, nondecreasing — documents must be stored
     back to back, the layout ``SyntheticCorpus`` and the compiler use).
+    ``sent_lengths`` and ``doc_sents``, where the dict has them, give the
+    corpus its sentence structure (:meth:`ShardedCorpusWriter.add_docs`).
     """
     if isinstance(corpus, SyntheticCorpus):
         corpus = corpus.generate()
@@ -439,7 +536,9 @@ def write_sharded_corpus(corpus, path: str, shard_tokens: int = 1 << 22,
         n_docs = int(doc_ids.max()) + 1 if len(doc_ids) else 0
         lengths = np.bincount(doc_ids, minlength=n_docs).astype(np.int64)
     return ShardedCorpusWriter(path, shard_tokens=shard_tokens,
-                               vocab=vocab).add_docs(tokens, lengths).close()
+                               vocab=vocab).add_docs(
+        tokens, lengths, corpus.get("sent_lengths"),
+        corpus.get("doc_sents")).close()
 
 
 # ---------------------------------------------------------------------------
@@ -472,20 +571,23 @@ class ShardedCorpus:
     """
 
     def __init__(self, path: str, manifest: dict, lengths: np.ndarray,
-                 hosts: Optional[HostAssignment] = None):
+                 hosts: Optional[HostAssignment] = None, sentences=None):
         self.path = str(path)
         self.hosts = hosts
         self._mmaps: dict[int, np.ndarray] = {}
         self._lock = threading.Lock()   # gather_tokens runs on the prefetch
         self.bytes_read = 0             # thread concurrently with held-out
         self.reads = 0                  # slicing on the consumer thread
-        self._install(manifest, lengths)
+        self._install(manifest, lengths, sentences)
 
-    def _install(self, manifest: dict, lengths: np.ndarray) -> None:
-        """Validate and adopt one committed (manifest, lengths) snapshot.
-        All derived arrays are built first and published together under the
-        lock, so a concurrent :meth:`gather_tokens` sees either the old or
-        the new snapshot, never a mix."""
+    def _install(self, manifest: dict, lengths: np.ndarray,
+                 sentences=None) -> None:
+        """Validate and adopt one committed (manifest, lengths) snapshot,
+        with its ``(doc_sents, sent_lengths)`` where the corpus has
+        sentence structure.  All derived arrays are built first and
+        published together under the lock, so a concurrent
+        :meth:`gather_tokens` sees either the old or the new snapshot,
+        never a mix."""
         lengths = np.asarray(lengths, np.int64)
         if len(lengths) < int(manifest["n_docs"]):
             raise ValueError(
@@ -515,10 +617,18 @@ class ShardedCorpus:
             for sid, s in enumerate(manifest["shards"]):
                 doc_owner[int(s["doc_start"]):int(s["doc_end"])] = \
                     shard_owner[sid]
+        doc_sents = sent_lengths = sent_offsets = None
+        if sentences is not None:
+            doc_sents, sent_lengths = sentences
+            # sent_offsets[d] is doc d's first sentence; (n_docs + 1,) int64
+            sent_offsets = np.concatenate([[0], np.cumsum(doc_sents)])
         with self._lock:
             self.manifest = manifest
             self.lengths = lengths
             self.offsets = offsets
+            self.doc_sents = doc_sents
+            self.sent_lengths = sent_lengths
+            self.sent_offsets = sent_offsets
             self._shard_tok_start = tok_start
             self._shard_tok_end = tok_end
             self.shard_owner = shard_owner
@@ -550,7 +660,7 @@ class ShardedCorpus:
                 f"{self.n_docs}); sharded corpora are append-only — was the "
                 f"directory rewritten?")
         lengths = np.load(os.path.join(self.path, _LENGTHS))
-        self._install(manifest, lengths)
+        self._install(manifest, lengths, _load_sentences(self.path, manifest))
         return True
 
     @classmethod
@@ -567,7 +677,8 @@ class ShardedCorpus:
         if manifest.get("format") != _FORMAT:
             raise ValueError(f"{mf}: not a {_FORMAT} manifest")
         lengths = np.load(os.path.join(str(path), _LENGTHS))
-        return cls(path, manifest, lengths, hosts=hosts)
+        return cls(path, manifest, lengths, hosts=hosts,
+                   sentences=_load_sentences(path, manifest))
 
     # -- metadata ---------------------------------------------------------
     @property
@@ -577,6 +688,14 @@ class ShardedCorpus:
     @property
     def n_tokens(self) -> int:
         return int(self.manifest["n_tokens"])
+
+    @property
+    def sentences(self):
+        """``(doc_sents, sent_lengths)``, or ``None`` for a corpus written
+        without sentence structure."""
+        if self.doc_sents is None:
+            return None
+        return self.doc_sents, self.sent_lengths
 
     @property
     def vocab(self) -> int:
@@ -695,41 +814,65 @@ class ShardedCorpus:
         return np.concatenate(pieces) if pieces else np.zeros(0, np.int32)
 
     def resident(self) -> dict:
-        """Materialize the whole corpus (``tokens``/``doc_ids``/``lengths``)
-        — for tests and corpora small enough to run both ways; defeats the
+        """Materialize the whole corpus (``tokens``/``doc_ids``/``lengths``,
+        and ``doc_sents``/``sent_lengths`` where it has sentences) — for
+        tests and corpora small enough to run both ways; defeats the
         point at scale."""
         tokens = self.gather_tokens(np.arange(self.n_docs))
         doc_ids = np.repeat(np.arange(self.n_docs, dtype=np.int32),
                             self.lengths)
-        return {"tokens": tokens, "doc_ids": doc_ids,
-                "lengths": self.lengths.copy()}
+        out = {"tokens": tokens, "doc_ids": doc_ids,
+               "lengths": self.lengths.copy()}
+        if self.doc_sents is not None:
+            out.update(doc_sents=self.doc_sents.copy(),
+                       sent_lengths=self.sent_lengths.copy())
+        return out
 
 
 # ---------------------------------------------------------------------------
 # full-size program template + sharded minibatch slicing
 # ---------------------------------------------------------------------------
 
+class _OnDisk:
+    """Marks a per-token array that a sharded template never holds: the
+    slicer rebuilds each batch's part of it from the corpus files, and
+    reading it whole fails here instead of returning wrong rows."""
+
+    def __init__(self, what: str):
+        self.what = what
+
+    def __repr__(self) -> str:
+        return f"<{self.what}: on disk>"
+
+    def __array__(self, *_, **__):
+        raise TypeError(f"a sharded template holds no {self.what}; "
+                        f"slice batches with slice_sharded")
+
+
 def _token_plate_spec(program):
     """The (latent, child) pair of a token-plate program, or raise.
 
     The sharded slicer supports the corpus-shaped model family: exactly one
-    latent selector living *on* the observed token plate (no ``zmap``), one
-    specialized child (rows are the selector value: ``base is None``,
-    ``stride == 1`` — LDA's shape), no static factors.  Models whose
-    per-token index arrays cannot be rebuilt from (tokens, lengths) alone
-    (SLDA's sentence maps, DCMLDA's per-doc row bases, naive Bayes'
-    doc-level latents) need the resident pipeline.
+    latent selector, one specialized child (rows are the selector value:
+    ``base is None``, ``stride == 1``), no static factors.  The latent
+    lives either *on* the observed token plate (no ``zmap``: LDA's shape)
+    or on a segment plate between the documents and the tokens (the child
+    maps each token to its segment with a ``zmap``: SLDA's sentences), so
+    every per-token index array can be rebuilt from the corpus's lengths
+    and sentence lengths.  DCMLDA's per-document row bases and naive
+    Bayes' document-level latents need the resident pipeline.
     """
     if (len(program.latents) == 1 and not program.statics
             and len(program.latents[0].children) == 1):
         spec = program.latents[0]
         f = spec.children[0]
-        if f.specialized and f.zmap is None:
+        if f.specialized:
             return spec, f
     raise ValueError(
         f"model {program.name} is outside the sharded-corpus family (need "
-        f"one token-plate latent with one specialized child and no static "
-        f"factors, like LDA); use the resident pipeline")
+        f"one token-plate or sentence-plate latent with one specialized "
+        f"child and no static factors, like LDA or SLDA); use the resident "
+        f"pipeline")
 
 
 def sharded_template(model, corpus: ShardedCorpus,
@@ -742,11 +885,14 @@ def sharded_template(model, corpus: ShardedCorpus,
     on a deep copy of ``model`` and compiled to capture the program
     *structure*; the specs are then rescaled to the corpus: local
     Dirichlets get ``g = n_docs`` rows, ``meta["pstar_size"] = n_docs``,
-    the latent spec ``n = n_tokens``.  The template's per-token arrays
-    (``prior_rows``, child ``values``, ``group``) are set to ``None`` —
-    :func:`slice_sharded` rebuilds each minibatch's slice from the shards
-    instead, and any resident-path access fails loudly.  The caller's
-    ``model`` is left untouched (it really does stay unobserved).
+    the latent spec ``n = n_tokens`` (``n_sents`` for a latent on a
+    sentence plate, whose corpus must carry sentence lengths).  The
+    template's per-token arrays (``prior_rows``, child ``values``,
+    ``group``) are set to ``None`` and a sentence latent's ``zmap`` to an
+    on-disk marker — :func:`slice_sharded` rebuilds each minibatch's slice
+    from the shards instead, and any resident-path access fails loudly.
+    The caller's ``model`` is left untouched (it really does stay
+    unobserved).
 
     ``capacity_docs`` — padded-growth headroom for *streaming* corpora:
     local Dirichlets get ``capacity_docs`` rows (documents committed later
@@ -771,11 +917,33 @@ def sharded_template(model, corpus: ShardedCorpus,
     # derived from it — is identical on every host
     reader = corpus
     if corpus.hosts is not None:
-        reader = ShardedCorpus(corpus.path, corpus.manifest, corpus.lengths)
+        reader = ShardedCorpus(corpus.path, corpus.manifest, corpus.lengths,
+                               sentences=corpus.sentences)
     proto_tokens = reader.gather_tokens(np.arange(p))
     proto_ids = np.repeat(np.arange(p, dtype=np.int32), corpus.lengths[:p])
+    # a token plate nested in a plate under the documents (SLDA's
+    # sentences) is bound from the corpus's sentence lengths
+    token_plate = model.net.rvs[observe].plate
+    segment = token_plate.parent
+    if segment.parent is model.net.toplevel:
+        segment = None
+    elif corpus.sentences is None:
+        raise ValueError(
+            f"{observe!r} lies in plate {segment.name!r} under the "
+            f"documents, which needs a corpus written with sentence "
+            f"lengths (ShardedCorpusWriter.add_docs(sent_lengths=, "
+            f"doc_sents=))")
     try:
-        model[observe].observe(proto_tokens, segment_ids=proto_ids)
+        if segment is None:
+            model[observe].observe(proto_tokens, segment_ids=proto_ids)
+        else:
+            n_s = int(corpus.doc_sents[:p].sum())
+            proto_sent = np.repeat(np.arange(n_s, dtype=np.int32),
+                                   corpus.sent_lengths[:n_s])
+            proto_ids = np.repeat(np.arange(p, dtype=np.int32),
+                                  corpus.doc_sents[:p])
+            model[observe].observe(proto_tokens, segment_ids=proto_sent)
+            model.bind(segment.name, proto_ids)
     except ValueError as e:
         raise ValueError(f"corpus (vocab {corpus.vocab}) does not fit "
                          f"{observe!r}: {e}") from e
@@ -784,10 +952,12 @@ def sharded_template(model, corpus: ShardedCorpus,
     spec, f = _token_plate_spec(proto)
     if proto.meta.get("pstar") is None:
         raise ValueError("sharded SVI needs a '?' partition plate")
-    if spec.group is None or not np.array_equal(spec.prior_rows, proto_ids):
+    if (spec.group is None or not np.array_equal(spec.prior_rows, proto_ids)
+            or (f.zmap is None) != (segment is None)):
         raise ValueError(
-            f"latent {spec.name} must live on the token plate directly "
-            f"under the partition plate (one prior row per document)")
+            f"latent {spec.name} must live on the token plate, or on the "
+            f"sentence plate, directly under the partition plate (one "
+            f"prior row per document)")
     if corpus.vocab > proto.dirichlets[f.dir_name].k:
         raise ValueError(
             f"corpus vocab {corpus.vocab} exceeds {f.dir_name}'s dimension "
@@ -798,6 +968,7 @@ def sharded_template(model, corpus: ShardedCorpus,
                          f"partition group for sharded slicing")
 
     n_docs, n_tokens = corpus.n_docs, corpus.n_tokens
+    n_latent = n_tokens if segment is None else int(corpus.doc_sents.sum())
     cap_docs = n_docs if capacity_docs is None else int(capacity_docs)
     if cap_docs < n_docs:
         raise ValueError(f"capacity_docs={cap_docs} is below the corpus's "
@@ -809,13 +980,15 @@ def sharded_template(model, corpus: ShardedCorpus,
         else:
             dirichlets[name] = dc.replace(
                 d, g=cap_docs, group_rows=np.arange(cap_docs, dtype=np.int32))
-    children = [dc.replace(f, values=None, n_z=n_tokens)]
-    latents = [dc.replace(spec, n=n_tokens, prior_rows=None,
+    zmap = None if segment is None else _OnDisk(f"{f.x_name} zmap")
+    children = [dc.replace(f, values=None, zmap=zmap, n_z=n_latent)]
+    latents = [dc.replace(spec, n=n_latent, prior_rows=None,
                           children=children, group=None)]
 
     plate_sizes = dict(proto.plate_sizes)
-    token_plate = model.net.rvs[observe].plate
     plate_sizes[token_plate.name] = n_tokens
+    if segment is not None:
+        plate_sizes[segment.name] = n_latent
     plate_sizes[proto.meta["pstar"]] = cap_docs
     layout, off = {}, 0
     for rv in proto.net.rvs.values():
@@ -833,15 +1006,33 @@ def sharded_template(model, corpus: ShardedCorpus,
 
 def sharded_caps(template, corpus: ShardedCorpus, groups) -> dict[str, int]:
     """The exact caps :func:`slice_sharded` would realize for ``groups``
-    under no padding policy — computed from ``corpus.lengths`` alone, with
-    **no shard I/O**.  The distributed batch builder probes per-shard caps
-    this way instead of slicing every sub-minibatch twice (which would
-    double the disk reads)."""
+    under no padding policy — computed from ``corpus.lengths`` (and
+    ``doc_sents``) alone, with **no shard I/O**.  Distributed batch
+    assembly probes per-shard caps this way instead of slicing every
+    sub-minibatch twice (which would double the disk reads)."""
     spec, f = _token_plate_spec(template)
     groups = np.unique(np.asarray(groups, np.int64))
-    nz = int(corpus.lengths[groups].sum())
+    nt = int(corpus.lengths[groups].sum())
+    nz = nt if f.zmap is None else int(corpus.doc_sents[groups].sum())
     return {spec.prior_dir: max(len(groups), 1), spec.name: max(nz, 1),
-            f.x_name: max(nz, 1)}
+            f.x_name: max(nt, 1)}
+
+
+def _segment_maps(corpus: ShardedCorpus, groups: np.ndarray):
+    """A batch's sentence structure: each sentence's batch-local document
+    row and each token's batch-local sentence, in document order.
+    Returns ``(prior_rows (n_s,) int32, zmap (n_t,) int32)``."""
+    per_doc = corpus.doc_sents[groups]
+    n_s = int(per_doc.sum())
+    # global id of each of the batch's sentences: each document's first
+    # sentence plus the sentence's place within its document
+    starts = np.repeat(corpus.sent_offsets[groups] - np.cumsum(per_doc)
+                       + per_doc, per_doc)
+    sent_ids = starts + np.arange(n_s)
+    prior_rows = np.repeat(np.arange(len(groups), dtype=np.int32), per_doc)
+    zmap = np.repeat(np.arange(n_s, dtype=np.int32),
+                     corpus.sent_lengths[sent_ids])
+    return prior_rows, zmap
 
 
 def slice_sharded(template, corpus: ShardedCorpus, groups, caps_fn=None):
@@ -849,10 +1040,11 @@ def slice_sharded(template, corpus: ShardedCorpus, groups, caps_fn=None):
 
     Builds one minibatch's ``(arrays, dir_rows, caps, n_tokens)`` by reading
     only the shards the batch's documents live in; every array (values,
-    prior rows, masks, sentinel padding, caps) is constructed to be bitwise
-    identical to what ``slice_arrays`` would produce from the equivalent
-    resident program — the property that makes sharded and resident SVI
-    bitwise-interchangeable (``tests/test_store.py``).
+    prior rows, zmaps, masks, sentinel padding, caps) is constructed to be
+    bitwise identical to what ``slice_arrays`` would produce from the
+    equivalent resident program — the property that makes sharded and
+    resident SVI bitwise-interchangeable (``tests/test_store.py``).  A
+    sentence latent's maps are built in a ``store.segments`` span.
     """
     # the exact padding/mask conventions of the resident slicer — the
     # bitwise contract lives in one place (compiler.py)
@@ -884,20 +1076,27 @@ def slice_sharded(template, corpus: ShardedCorpus, groups, caps_fn=None):
     caps[spec.prior_dir] = cap_d
 
     lengths_b = corpus.lengths[groups]
-    nz = int(lengths_b.sum())
+    nt = int(lengths_b.sum())
+    zmap = None
+    if f.zmap is None:                  # one latent per token
+        prior_rows = np.repeat(np.arange(g_b, dtype=np.int32), lengths_b)
+    else:                               # one latent per sentence
+        with TraceAnnotation("store.segments"):
+            prior_rows, zmap = _segment_maps(corpus, groups)
+    nz = len(prior_rows)
     capz = max(int(cap_of(spec.name, nz)), 1)
     caps[spec.name] = capz
-    prior_rows = np.repeat(np.arange(g_b, dtype=np.int64),
-                           lengths_b).astype(np.int32)
     arrays[spec.name] = {"prior_rows": _padded(prior_rows, capz),
                          "mask": _mask(capz, nz)}
 
-    caps[f.x_name] = capz                           # zmap-None child: capt=capz
+    capt = capz if zmap is None else max(int(cap_of(f.x_name, nt)), 1)
+    caps[f.x_name] = capt
     arrays[f.x_name] = {
         "values": _padded(corpus.gather_tokens(groups).astype(np.int32),
-                          capz),
-        "zmap": None, "base": None, "mask": _mask(capz, nz)}
-    return arrays, dir_rows, caps, nz
+                          capt),
+        "zmap": None if zmap is None else _padded(zmap, capt),
+        "base": None, "mask": _mask(capt, nt)}
+    return arrays, dir_rows, caps, nt
 
 
 # ---------------------------------------------------------------------------

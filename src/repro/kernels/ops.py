@@ -243,7 +243,10 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
         also never materializes the (N_token, K) working set.
 
     Every route runs under the ``kernels.zstats`` named scope, so its
-    device ops carry that name in a profile.
+    device ops carry that name in a profile; a segment latent's logits
+    reduction and its scatter back run under ``kernels.zstats.segments``
+    inside it (the whole ``fused-zmap`` kernel, or the ``ref`` route's two
+    token passes).
     """
     with jax.named_scope("kernels.zstats"):
         return _zstats(table_prior, prior_rows, children, zmask, tables,
@@ -263,8 +266,10 @@ def _zstats(table_prior, prior_rows, children, zmask, tables, bucketing):
             if fusable_zmap(table_prior, children, tables,
                             n_latent=prior_rows.shape[0]):
                 assert route.path == "fused-zmap", route
-                return zstats_zmap(table_prior, prior_rows, children,
-                                   zmask, tables=tables, interpret=interp)
+                with jax.named_scope("kernels.zstats.segments"):
+                    return zstats_zmap(table_prior, prior_rows, children,
+                                       zmask, tables=tables,
+                                       interpret=interp)
         else:
             from .fused_zstats import fusable, zstats as _zstats_pallas
             if fusable(table_prior, children, tables):

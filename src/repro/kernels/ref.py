@@ -242,25 +242,28 @@ def _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k):
 
 def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k):
     """Segment latents: accumulate per-instance logits (cross-token
-    reduction), then stream the child token plates against them."""
+    reduction), then stream the child token plates against them.  Both
+    token passes run under the ``kernels.zstats.segments`` named scope."""
     nz = prior_rows.shape[0]
     gp = elog_prior.shape[0]
     logits = elog_prior[prior_rows].astype(jnp.float32)
 
-    for i, c in enumerate(children):
-        if c.zmap is None:
-            logits = logits + _child_messages(c, c.values, c.base, c.mask, k)
-            continue
+    with jax.named_scope("kernels.zstats.segments"):
+        for i, c in enumerate(children):
+            if c.zmap is None:
+                logits = logits + _child_messages(c, c.values, c.base,
+                                                  c.mask, k)
+                continue
 
-        def msg_body(acc, xs, c=c, i=i):
-            e = _child_messages(c, xs[f"values{i}"], xs.get(f"base{i}"),
-                                xs.get(f"mask{i}"), k)
-            return acc + jax.ops.segment_sum(e, xs[f"zmap{i}"],
-                                             num_segments=nz)
+            def msg_body(acc, xs, c=c, i=i):
+                e = _child_messages(c, xs[f"values{i}"], xs.get(f"base{i}"),
+                                    xs.get(f"mask{i}"), k)
+                return acc + jax.ops.segment_sum(e, xs[f"zmap{i}"],
+                                                 num_segments=nz)
 
-        logits = logits + _scan_chunks(
-            _token_xs(c, i), c.values.shape[0], chunk,
-            jnp.zeros((nz, k), jnp.float32), msg_body)
+            logits = logits + _scan_chunks(
+                _token_xs(c, i), c.values.shape[0], chunk,
+                jnp.zeros((nz, k), jnp.float32), msg_body)
 
     r, lse = zstep(logits)
     if zmask is not None:
@@ -270,22 +273,23 @@ def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k):
     pstats = jnp.zeros((gp, k), jnp.float32).at[prior_rows].add(r)
 
     cstats = []
-    for i, c in enumerate(children):
-        if c.zmap is None:
-            s = _child_stats_native(c, _child_stats_init(c), r, c.values,
-                                    c.base, c.mask, k)
+    with jax.named_scope("kernels.zstats.segments"):
+        for i, c in enumerate(children):
+            if c.zmap is None:
+                s = _child_stats_native(c, _child_stats_init(c), r, c.values,
+                                        c.base, c.mask, k)
+                cstats.append(_child_stats_finish(c, s))
+                continue
+
+            def st_body(cs, xs, c=c, i=i):
+                w = r[xs[f"zmap{i}"]]
+                return _child_stats_native(c, cs, w, xs[f"values{i}"],
+                                           xs.get(f"base{i}"),
+                                           xs.get(f"mask{i}"), k)
+
+            s = _scan_chunks(_token_xs(c, i), c.values.shape[0], chunk,
+                             _child_stats_init(c), st_body)
             cstats.append(_child_stats_finish(c, s))
-            continue
-
-        def st_body(cs, xs, c=c, i=i):
-            w = r[xs[f"zmap{i}"]]
-            return _child_stats_native(c, cs, w, xs[f"values{i}"],
-                                       xs.get(f"base{i}"),
-                                       xs.get(f"mask{i}"), k)
-
-        s = _scan_chunks(_token_xs(c, i), c.values.shape[0], chunk,
-                         _child_stats_init(c), st_body)
-        cstats.append(_child_stats_finish(c, s))
     return lse_sum, pstats, tuple(cstats)
 
 

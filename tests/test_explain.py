@@ -183,6 +183,47 @@ def test_traced_step_agrees_with_plan(monkeypatch, small_corpus):
     assert np.isfinite(m.lower_bound)
 
 
+def test_sharded_slda_plan_at_the_nytimes_step(monkeypatch, tmp_path):
+    """SLDA over a sharded corpus at the step shapes of the benchmark's
+    ``train.slda-nytimes`` cell (V=102,660, K=256, 512 documents of 335
+    tokens in 28 sentences a step): the plan slices the first batch from
+    the corpus, reports the sentence axis, and routes the segment latent
+    to ``ref`` (its tables and logits exceed VMEM); ``ops.zstats`` at the
+    same shapes dispatches there."""
+    from repro.core import models
+    from repro.core.svi import SVIConfig
+    from repro.data import write_sharded_corpus
+    v, k, n_docs = 102_660, 256, 576
+    sents = np.array([12] * 27 + [11])
+    store = write_sharded_corpus(
+        {"tokens": np.zeros(335 * n_docs, np.int32),
+         "lengths": np.full(n_docs, 335), "doc_sents": np.full(n_docs, 28),
+         "sent_lengths": np.tile(sents, n_docs)}, str(tmp_path), vocab=v)
+    model = models.make("slda", alpha=0.1, beta=0.05, K=k, V=v)
+    cfg = SVIConfig(batch_size=512, holdout_frac=64 / n_docs,
+                    pad_multiple=256)
+    plan = explain_plan(model, cfg, corpus=store, backend="pallas")
+    assert not plan.diagnostics and not model.observations
+    assert plan.caps == {"theta": 512, "z": 14336, "x": 171520}
+    (r,) = plan.routes
+    assert (r.path, r.n_latent, r.n_tokens) == ("ref", 14336, 171520)
+    assert "exceed the VMEM table budget" in r.reason
+    assert any(n.startswith("sentence axis: latent z on plate sents, "
+                            "16128 instances") for n in plan.notes)
+
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    kops.reset_backend_cache()
+    taken: list = []
+    _stub_kernels(monkeypatch, taken)
+    sds = jax.ShapeDtypeStruct
+    child = kops.ZChild(elog=sds((k, v), np.float32),
+                        values=sds((171520,), np.int32), stride=1,
+                        zmap=sds((171520,), np.int32), base=None)
+    kops.zstats(sds((512, k), np.float32), sds((14336,), np.int32),
+                (child,), tables="alpha")
+    assert taken == ["ref"]
+
+
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------

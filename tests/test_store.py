@@ -293,3 +293,167 @@ def test_build_infer_step_out_of_core(store):
         state, elbo = step_fn(state)
     assert np.isfinite(float(elbo)) and int(state.step) == 2
     step_fn.svi.close()
+
+
+# ---------------------------------------------------------------------------
+# sentence structure: SLDA on the sharded path
+# ---------------------------------------------------------------------------
+
+def _sentences(lengths, seed=1):
+    """Each document cut at random into about one sentence per 8 tokens
+    (a document of a few tokens keeps one sentence)."""
+    rng = np.random.default_rng(seed)
+    doc_sents, sent_lengths = [], []
+    for n in np.asarray(lengths):
+        s = max(1, int(round(n / 8)))
+        cuts = np.sort(rng.choice(np.arange(1, n), s - 1, replace=False))
+        sent_lengths.extend(np.diff(np.concatenate([[0], cuts, [n]])))
+        doc_sents.append(s)
+    return np.asarray(doc_sents), np.asarray(sent_lengths)
+
+
+@pytest.fixture(scope="module")
+def slda_corpus(small_corpus):
+    doc_sents, sent_lengths = _sentences(small_corpus["lengths"])
+    return dict(small_corpus, doc_sents=doc_sents, sent_lengths=sent_lengths)
+
+
+@pytest.fixture(scope="module")
+def slda_store(slda_corpus, tmp_path_factory):
+    path = tmp_path_factory.mktemp("slda_shards")
+    return write_sharded_corpus(slda_corpus, str(path), shard_tokens=500)
+
+
+def _slda():
+    return models.make("slda", alpha=0.1, beta=0.05, K=3, V=30)
+
+
+@pytest.fixture(scope="module")
+def slda_program(slda_corpus):
+    """The resident SLDA program over the same corpus and sentences."""
+    n_s = len(slda_corpus["sent_lengths"])
+    m = _slda()
+    m["x"].observe(slda_corpus["tokens"],
+                   segment_ids=np.repeat(np.arange(n_s),
+                                         slda_corpus["sent_lengths"]))
+    m.bind("sents", np.repeat(np.arange(len(slda_corpus["doc_sents"])),
+                              slda_corpus["doc_sents"]))
+    return m.compile()
+
+
+def test_sentences_roundtrip(slda_corpus, slda_store):
+    sc = ShardedCorpus.open(slda_store.path)
+    assert sc.manifest["n_sents"] == len(slda_corpus["sent_lengths"])
+    r = sc.resident()
+    np.testing.assert_array_equal(r["doc_sents"], slda_corpus["doc_sents"])
+    np.testing.assert_array_equal(r["sent_lengths"],
+                                  slda_corpus["sent_lengths"])
+    np.testing.assert_array_equal(r["tokens"], slda_corpus["tokens"])
+
+
+@pytest.mark.parametrize("groups", [np.arange(50), np.array([3, 17, 4, 44,
+                                                            9]),
+                                    np.array([0])])
+@pytest.mark.parametrize("padded", [False, True])
+def test_slda_slice_sharded_bitwise(slda_store, slda_program, groups,
+                                    padded):
+    """Sentence prior rows, zmaps, values, masks, sentinels and caps are
+    bitwise what the resident slicer gives."""
+    pad = (lambda name, n: -(-max(n, 1) // 64) * 64) if padded else None
+    tmpl = sharded_template(_slda(), slda_store)
+    a1, d1, c1, n1 = slice_arrays(slda_program, groups, pad)
+    a2, d2, c2, n2 = slice_sharded(tmpl, slda_store, groups, pad)
+    assert list(c1.items()) == list(c2.items()) and n1 == n2
+    assert a1["x"]["zmap"] is not None
+    for k in a1:
+        assert list(a1[k]) == list(a2[k])
+        for kk, x in a1[k].items():
+            if x is None:
+                assert a2[k][kk] is None
+            else:
+                assert x.dtype == a2[k][kk].dtype
+                np.testing.assert_array_equal(x, a2[k][kk])
+    for k in d1:
+        for kk, x in d1[k].items():
+            np.testing.assert_array_equal(x, d2[k][kk])
+    from repro.data.store import sharded_caps
+    assert sharded_caps(tmpl, slda_store, groups) == \
+        slice_sharded(tmpl, slda_store, groups, None)[2]
+
+
+def test_slda_template_matches_resident_program(slda_store, slda_program):
+    tmpl = sharded_template(_slda(), slda_store)
+    assert tmpl.latents[0].n == slda_program.latents[0].n
+    assert tmpl.vertex_layout == slda_program.vertex_layout
+    assert tmpl.plate_sizes == slda_program.plate_sizes
+    with pytest.raises(TypeError, match="slice_sharded"):
+        np.asarray(tmpl.latents[0].children[0].zmap)
+
+
+def test_sharded_slda_svi_bitwise_equals_resident(slda_store, slda_program):
+    """10 steps and two held-out scores: the sharded SLDA fit is bitwise
+    the resident one."""
+    cfg = SVIConfig(batch_size=12, holdout_frac=0.1, holdout_every=5,
+                    pad_multiple=64, seed=0)
+    res = SVI(slda_program, cfg)
+    s_res, h_res = res.fit(steps=10)
+    sh = SVI(_slda(), cfg, corpus=ShardedCorpus.open(slda_store.path))
+    s_sh, h_sh = sh.fit(steps=10)
+    sh.close()
+    for n in s_res.posteriors:
+        np.testing.assert_array_equal(np.asarray(s_res.posteriors[n]),
+                                      np.asarray(s_sh.posteriors[n]))
+    assert h_res["elbo"] == h_sh["elbo"]
+    assert len(h_sh["heldout"]) == 2
+    assert h_res["heldout"] == h_sh["heldout"]
+
+
+def test_manifest_without_sentences_opens_as_lda(small_corpus, tmp_path):
+    """A store whose manifest predates sentence structure (no ``n_sents``,
+    no sentence files) opens and trains as an LDA corpus; SLDA asks for
+    sentence lengths."""
+    import json
+    import os
+    sc = write_sharded_corpus(small_corpus, str(tmp_path), shard_tokens=500)
+    with open(os.path.join(sc.path, "manifest.json")) as fh:
+        manifest = json.load(fh)
+    old_keys = ["format", "version", "commit", "n_docs", "n_tokens", "vocab",
+                "dtype", "shards", "writer"]
+    assert list(manifest) == old_keys
+    assert set(os.listdir(sc.path)) == {"manifest.json", "lengths.npy"} | {
+        s["path"] for s in manifest["shards"]}
+    old = ShardedCorpus.open(sc.path)
+    assert old.sentences is None and "doc_sents" not in old.resident()
+    svi = SVI(_lda(), SVIConfig(batch_size=16, seed=0), corpus=old)
+    _, hist = svi.fit(steps=2)
+    svi.close()
+    assert np.isfinite(hist["elbo"]).all()
+    with pytest.raises(ValueError, match="sentence lengths"):
+        sharded_template(_slda(), old)
+
+
+def test_writer_sentences_validate_and_reopen(slda_corpus, tmp_path):
+    toks, lens = slda_corpus["tokens"], slda_corpus["lengths"]
+    ds, sl = slda_corpus["doc_sents"], slda_corpus["sent_lengths"]
+    w = ShardedCorpusWriter(str(tmp_path / "bad"))
+    with pytest.raises(ValueError, match="both"):
+        w.add_docs(toks, lens, sent_lengths=sl)
+    bad = sl.copy()
+    bad[0] += 1
+    with pytest.raises(ValueError, match="document 0"):
+        w.add_docs(toks, lens, bad, ds)
+    # two chunks, a commit between, a reopen: the sentences carry on
+    n1 = int(lens[:20].sum())
+    s1 = int(ds[:20].sum())
+    w = ShardedCorpusWriter(str(tmp_path / "ok"), shard_tokens=500)
+    w.add_docs(toks[:n1], lens[:20], sl[:s1], ds[:20])
+    live = w.commit()
+    with pytest.raises(ValueError, match="every chunk"):
+        w.add_docs(toks[n1:], lens[20:])
+    w2 = ShardedCorpusWriter.reopen(str(tmp_path / "ok"))
+    w2.add_docs(toks[n1:], lens[20:], sl[s1:], ds[20:])
+    w2.close()
+    assert live.refresh()
+    np.testing.assert_array_equal(live.sent_lengths, sl)
+    np.testing.assert_array_equal(live.doc_sents, ds)
+    np.testing.assert_array_equal(live.sent_offsets[1:], np.cumsum(ds))
