@@ -423,12 +423,12 @@ def build_local_scorer(program: VMPProgram, caps: dict[str, int],
         return posts
 
     def _fit_locals(posts, arrays):
+        # keep=local: the frozen globals come back as given, and no pass
+        # computes statistics of a global table
         st = VMPState(posts, jnp.zeros((), jnp.int32))
         for _ in range(inner_iters):
-            new, _ = _step_body(shadow, arrays, st)
-            st = VMPState({n: (new.posteriors[n] if n in local
-                               else posts[n]) for n in posts}, st.step)
-        _, elbo = _step_body(shadow, arrays, st)
+            st, _ = _step_body(shadow, arrays, st, keep=local)
+        _, elbo = _step_body(shadow, arrays, st, keep=local)
         return st, elbo
 
     def _drop_global_kl(elbo, posteriors):

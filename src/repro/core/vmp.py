@@ -109,7 +109,7 @@ def _program_arrays(program: VMPProgram) -> dict:
 
 def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
                axis_names: tuple = (), local_dirs: frozenset = frozenset(),
-               n_replicas: int = 1, elog_dtype=None):
+               n_replicas: int = 1, elog_dtype=None, keep=None):
     """One VMP iteration.  ``axis_names`` non-empty => running inside
     shard_map; stats of non-local Dirichlets are psum'd (the InferSpark
     partitioning: replicate the small posteriors, keep big plates local).
@@ -127,15 +127,28 @@ def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
     concentration tables the token plate reads — halving their HBM traffic
     — while the in-kernel digamma, softmax, stats accumulation, and the
     Dirichlet ELBO terms stay f32.
+
+    ``keep`` names the Dirichlets whose new posteriors the caller reads
+    (``None``: all of them).  A Dirichlet outside it is returned as its
+    input posterior, and a latent none of whose children's Dirichlets is
+    kept asks the token plate for no child statistics
+    (``kops.zstats(child_stats=False)``); the ELBO is the same either way.
     """
     from repro.kernels import ops as kops
+
+    def kept(name):
+        return keep is None or name in keep
 
     amsg = state.posteriors if elog_dtype is None else \
         {n: p.astype(elog_dtype) for n, p in state.posteriors.items()}
 
     elbo = jnp.zeros((), jnp.float32)
     stats = {n: jnp.zeros((d.g, d.k), jnp.float32)
-             for n, d in program.dirichlets.items()}
+             for n, d in program.dirichlets.items() if kept(n)}
+
+    def add_stats(name, st):
+        if name in stats:
+            stats[name] = stats[name] + st
 
     # host-precomputed streamed-table bucketing: the permutation depends
     # only on the program's static observed values, so it is computed once
@@ -156,21 +169,25 @@ def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
                         base=arrays[f.x_name].get("base"),
                         mask=arrays[f.x_name].get("mask"))
             for f in spec.children)
-        bkey = (spec.name, arrays[spec.name]["prior_rows"].shape[0])
-        if bkey not in bcache:
-            bcache[bkey] = kops.host_bucketing(
-                amsg[spec.prior_dir], arrays[spec.name]["prior_rows"],
-                children, tables="alpha")
+        child_stats = any(kept(f.dir_name) for f in spec.children)
+        bucketing = None   # a local-only pass streams no table
+        if child_stats:
+            bkey = (spec.name, arrays[spec.name]["prior_rows"].shape[0])
+            if bkey not in bcache:
+                bcache[bkey] = kops.host_bucketing(
+                    amsg[spec.prior_dir], arrays[spec.name]["prior_rows"],
+                    children, tables="alpha")
+            bucketing = bcache[bkey]
         lse_sum, pstats, cstats = kops.zstats(
             amsg[spec.prior_dir], arrays[spec.name]["prior_rows"], children,
             zmask=arrays[spec.name].get("mask"), tables="alpha",
-            bucketing=bcache[bkey])
+            bucketing=bucketing, child_stats=child_stats)
         elbo = elbo + lse_sum
         # prior-factor stats (theta <- z)
-        stats[spec.prior_dir] = stats[spec.prior_dir] + pstats
-        # child-factor stats (phi <- x weighted by r)
+        add_stats(spec.prior_dir, pstats)
+        # child-factor stats (phi <- x weighted by r); none when not kept
         for f, cs in zip(spec.children, cstats):
-            stats[f.dir_name] = stats[f.dir_name] + cs
+            add_stats(f.dir_name, cs)
 
     selog: dict[str, jax.Array] = {}   # statics' Elog tables, on demand
     for s in program.statics:
@@ -180,14 +197,16 @@ def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
             selog[s.dir_name] = kops.dirichlet_expectation(
                 state.posteriors[s.dir_name])
         e = selog[s.dir_name][a["rows"], a["values"]]
-        ones = jnp.ones_like(a["values"], jnp.float32)
         if a.get("mask") is not None:
             e = e * a["mask"]
-            ones = ones * a["mask"]
         elbo = elbo + e.sum()
-        flat = a["rows"].astype(jnp.int32) * d.k + a["values"]
-        add = jax.ops.segment_sum(ones, flat, num_segments=d.g * d.k)
-        stats[s.dir_name] = stats[s.dir_name] + add.reshape(d.g, d.k)
+        if kept(s.dir_name):
+            ones = jnp.ones_like(a["values"], jnp.float32)
+            if a.get("mask") is not None:
+                ones = ones * a["mask"]
+            flat = a["rows"].astype(jnp.int32) * d.k + a["values"]
+            add = jax.ops.segment_sum(ones, flat, num_segments=d.g * d.k)
+            add_stats(s.dir_name, add.reshape(d.g, d.k))
 
     # Dirichlet ELBO terms + posterior updates
     new_posts = {}
@@ -196,15 +215,17 @@ def _step_body(program: VMPProgram, arrays: dict, state: VMPState,
         with jax.named_scope("vmp.elbo"):
             term = dists.dirichlet_elbo_term(prior, state.posteriors[name],
                                              selog.get(name))
-        st = stats[name]
+        st = stats.get(name)
         if axis_names and name not in local_dirs:
-            st = jax.lax.psum(st, axis_names)
+            if st is not None:
+                st = jax.lax.psum(st, axis_names)
             # local-dirichlet ELBO terms are per-shard disjoint (summed by the
             # final psum); a replicated dirichlet's term would be counted once
             # per shard, so scale it out here.
             term = term / n_replicas
         elbo = elbo + term
-        new_posts[name] = prior * jnp.ones_like(st) + st
+        new_posts[name] = state.posteriors[name] if st is None \
+            else prior * jnp.ones_like(st) + st
 
     if axis_names:
         elbo = jax.lax.psum(elbo, axis_names)

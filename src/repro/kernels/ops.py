@@ -130,7 +130,8 @@ def _table_bytes(table_prior, children, tables: str,
 
 def routing(table_prior, prior_rows=None, children=(), *,
             tables: str = "elog", backend: Optional[str] = None,
-            n_latent: Optional[int] = None) -> RouteInfo:
+            n_latent: Optional[int] = None,
+            child_stats: bool = True) -> RouteInfo:
     """Predict which kernel :func:`zstats` will dispatch to — without
     touching any backend or device state.
 
@@ -144,7 +145,11 @@ def routing(table_prior, prior_rows=None, children=(), *,
     :func:`zstats` asserts agreement at trace time, so this function and
     the dispatch can never drift.  ``backend`` defaults to this process's
     :func:`_backend` answer; pass ``"pallas"`` to plan for TPU from
-    anywhere.
+    anywhere.  ``child_stats=False`` plans a call whose caller reads no
+    child statistics: where the fused kernel would stream a table, such a
+    call takes ``ref`` (XLA's gathers, and no statistics of the streamed
+    table), since the kernel would gather and scatter every tile by
+    one-hot matmuls for statistics nobody reads.
     """
     from .fused_zmap import fusable_zmap
     from .fused_zstats import _TABLE_BUDGET, _plan
@@ -181,6 +186,11 @@ def routing(table_prior, prior_rows=None, children=(), *,
     if plan.target is None:
         return _route("fused", bn=plan.bn,
                       reason="all tables VMEM-resident")
+    if not child_stats:
+        return _route("ref",
+                      reason="local-only pass over a table above the VMEM "
+                             "budget; XLA gathers, no statistics of the "
+                             "streamed table")
     return _route("fused-streamed", target=plan.target, tile=plan.tl,
                   n_tiles=plan.n_tiles, bn=plan.bn,
                   reason=f"table over the VMEM budget; streaming "
@@ -194,16 +204,18 @@ def host_bucketing(table_prior, prior_rows, children, *,
     call whose observed index streams are trace-time constants (the
     full-batch engine's arrays).  Returns the numpy triple to pass back as
     ``zstats(..., bucketing=...)``, or ``None`` when there is nothing to
-    hoist (ref backend, resident layout, zmap children, or traced index
+    hoist (a call that does not take ``fused-streamed``, or traced index
     streams) — ``None`` is always safe to pass through."""
-    if _backend() == "ref":
+    if routing(table_prior, prior_rows, children,
+               tables=tables).path != "fused-streamed":
         return None
     from .fused_zstats import host_bucketing as _hb
     return _hb(table_prior, prior_rows, children, tables=tables)
 
 
 def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
-           zmask=None, *, tables: str = "elog", bucketing=None):
+           zmask=None, *, tables: str = "elog", bucketing=None,
+           child_stats: bool = True):
     """Fused token-plate substep: ``(lse_sum, prior_stats, child_stats)``.
 
     Inputs: ``table_prior`` — the ``(G, K)`` prior-Dirichlet table;
@@ -220,7 +232,9 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
     float32 sum of per-instance logsumexp (the token plate's ELBO term);
     ``prior_stats`` — ``(G, K)`` float32 responsibility scatters onto the
     prior rows; ``child_stats`` — per child a ``(Gc, Kc)`` float32 stats
-    table.
+    table.  With ``child_stats=False`` the caller reads no child
+    statistics and gets ``()`` in their place; ``lse_sum`` and
+    ``prior_stats`` are unchanged.
 
     ``bucketing`` — an optional :func:`host_bucketing` result: the
     streamed-table path's token permutation precomputed on the host (and
@@ -240,47 +254,64 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
         over-budget table behind a strided row computation, a segment
         latent whose tables exceed VMEM) falls back to the chunked ``ref``
         oracle, which streams token chunks through a ``lax.scan`` and so
-        also never materializes the (N_token, K) working set.
+        also never materializes the (N_token, K) working set;
+      - a ``child_stats=False`` call that would stream a table takes
+        ``ref`` too: XLA's gathers read the table, and the streamed
+        kernel's one-hot matmuls would only build statistics nobody reads.
 
     Every route runs under the ``kernels.zstats`` named scope, so its
     device ops carry that name in a profile; a segment latent's logits
     reduction and its scatter back run under ``kernels.zstats.segments``
     inside it (the whole ``fused-zmap`` kernel, or the ``ref`` route's two
-    token passes).
+    token passes).  A ``child_stats=False`` call runs under
+    ``kernels.zstats.local`` inside ``kernels.zstats``, on every route.
     """
     with jax.named_scope("kernels.zstats"):
-        return _zstats(table_prior, prior_rows, children, zmask, tables,
-                       bucketing)
+        if child_stats:
+            return _zstats(table_prior, prior_rows, children, zmask, tables,
+                           bucketing, True)
+        with jax.named_scope("kernels.zstats.local"):
+            return _zstats(table_prior, prior_rows, children, zmask, tables,
+                           bucketing, False)
 
 
-def _zstats(table_prior, prior_rows, children, zmask, tables, bucketing):
+def _zstats(table_prior, prior_rows, children, zmask, tables, bucketing,
+            child_stats):
     b = _backend()
     if b != "ref":
         interp = b == "pallas_interpret"
         # trace-time cross-check: the pure routing() prediction must agree
         # with the dispatch below (the EXPLAIN plan's accuracy contract)
         route = routing(table_prior, prior_rows, children, tables=tables,
-                        backend=b)
+                        backend=b, child_stats=child_stats)
         if any(c.zmap is not None for c in children):
             from .fused_zmap import fusable_zmap, zstats_zmap
             if fusable_zmap(table_prior, children, tables,
                             n_latent=prior_rows.shape[0]):
                 assert route.path == "fused-zmap", route
                 with jax.named_scope("kernels.zstats.segments"):
-                    return zstats_zmap(table_prior, prior_rows, children,
-                                       zmask, tables=tables,
-                                       interpret=interp)
+                    return _as_asked(zstats_zmap(
+                        table_prior, prior_rows, children, zmask,
+                        tables=tables, interpret=interp), child_stats)
         else:
-            from .fused_zstats import fusable, zstats as _zstats_pallas
-            if fusable(table_prior, children, tables):
+            from .fused_zstats import _plan, zstats as _zstats_pallas
+            plan = _plan(table_prior, children, tables)
+            # a streamed table's statistics are what the kernel's one-hot
+            # matmuls buy; a caller that reads none takes XLA's gathers
+            if plan is not None and (child_stats or plan.target is None):
                 assert route.path in ("fused", "fused-streamed"), route
-                return _zstats_pallas(table_prior, prior_rows, children,
-                                      zmask, tables=tables,
-                                      interpret=interp,
-                                      bucketing=bucketing)
+                return _as_asked(_zstats_pallas(
+                    table_prior, prior_rows, children, zmask, tables=tables,
+                    interpret=interp, bucketing=bucketing), child_stats)
         assert route.path == "ref", route
     return ref.zstats(table_prior, prior_rows, children, zmask,
-                      tables=tables)
+                      tables=tables, child_stats=child_stats)
+
+
+def _as_asked(out, child_stats: bool):
+    """A kernel's result as the caller asked for it: ``()`` in place of
+    child statistics it does not read."""
+    return out if child_stats else (out[0], out[1], ())
 
 
 def flash_attention(q, k, v, *, causal: bool = True):

@@ -161,7 +161,8 @@ def _token_xs(child: ZChild, i: int) -> dict:
 
 def zstats(elog_prior: jax.Array, prior_rows: jax.Array,
            children: tuple, zmask: Optional[jax.Array] = None,
-           chunk: int = ZSTATS_CHUNK, *, tables: str = "elog"):
+           chunk: int = ZSTATS_CHUNK, *, tables: str = "elog",
+           child_stats: bool = True):
     """Fused z-substep semantics: one streaming pass over the token plate.
 
     Computes, without ever materializing the (N, K) responsibilities or
@@ -175,7 +176,10 @@ def zstats(elog_prior: jax.Array, prior_rows: jax.Array,
 
     Returns ``(lse_sum, prior_stats, child_stats_tuple)`` — exactly the
     quantities ``core/vmp.py:_step_body`` needs; responsibilities are
-    intermediate values, never state.
+    intermediate values, never state.  ``child_stats=False`` leaves the
+    child accumulators out of the pass (the tuple is ``()``), so nothing
+    of the child tables' size is scattered; ``lse_sum`` and
+    ``prior_stats`` come from the same ops in the same order.
 
     Latents whose children carry a ``zmap`` (segment latents, e.g. SLDA
     sentences) need a cross-token reduction before the softmax, so they
@@ -198,11 +202,13 @@ def zstats(elog_prior: jax.Array, prior_rows: jax.Array,
     k = elog_prior.shape[1]
     if any(c.zmap is not None for c in children):
         return _zstats_segmented(elog_prior, prior_rows, children, zmask,
-                                 chunk, k)
-    return _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k)
+                                 chunk, k, child_stats)
+    return _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k,
+                        child_stats)
 
 
-def _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k):
+def _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k,
+                 child_stats):
     """Token plate == latent plate: a single fused scan, nothing (N, K)."""
     n = prior_rows.shape[0]
     gp = elog_prior.shape[0]
@@ -221,6 +227,7 @@ def _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k):
             lse = lse * zm
         lse_acc = lse_acc + lse.sum()
         pstats = pstats.at[rows].add(r)
+        # an empty carry (child_stats=False) zips to no scatter at all
         cstats = tuple(
             _child_stats_native(c, cs, r, xs[f"values{i}"],
                                 xs.get(f"base{i}"), xs.get(f"mask{i}"), k)
@@ -234,16 +241,19 @@ def _zstats_flat(elog_prior, prior_rows, children, zmask, chunk, k):
         xs.update(_token_xs(c, i))
     init = (jnp.zeros((), jnp.float32),
             jnp.zeros((gp, k), jnp.float32),
-            tuple(_child_stats_init(c) for c in children))
+            tuple(_child_stats_init(c) for c in children)
+            if child_stats else ())
     lse_sum, pstats, cstats = _scan_chunks(xs, n, chunk, init, body)
     return lse_sum, pstats, tuple(_child_stats_finish(c, cs)
                                   for c, cs in zip(children, cstats))
 
 
-def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k):
+def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k,
+                      child_stats):
     """Segment latents: accumulate per-instance logits (cross-token
     reduction), then stream the child token plates against them.  Both
-    token passes run under the ``kernels.zstats.segments`` named scope."""
+    token passes run under the ``kernels.zstats.segments`` named scope;
+    ``child_stats=False`` leaves out the second."""
     nz = prior_rows.shape[0]
     gp = elog_prior.shape[0]
     logits = elog_prior[prior_rows].astype(jnp.float32)
@@ -271,6 +281,8 @@ def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k):
         lse = lse * zmask
     lse_sum = lse.sum()
     pstats = jnp.zeros((gp, k), jnp.float32).at[prior_rows].add(r)
+    if not child_stats:
+        return lse_sum, pstats, ()
 
     cstats = []
     with jax.named_scope("kernels.zstats.segments"):

@@ -493,6 +493,105 @@ def test_zmap_kernel_refuses_streamed_prior():
         ref.zstats_blocked(tp, rows, ch)
 
 
+# ---------------------------------------------------------------------------
+# local-only passes: child_stats=False
+# ---------------------------------------------------------------------------
+
+# (prior rows, K, V, tokens): the NYTimes held-out scorer (1,024 documents,
+# 339,643 tokens padded to 256, phi 256 x 102,660), the PubMed one (K=1,000,
+# V=141,043, 89 tokens a document) and a VMEM-resident LDA
+ROUTE_SHAPES = {
+    "nytimes": (1024, 256, 102_660, 339_712),
+    "pubmed": (1024, 1000, 141_043, 91_136),
+    "resident": (64, 128, 2000, 4096),
+}
+
+
+@pytest.mark.parametrize("name,child_stats,path", [
+    ("nytimes", True, "fused-streamed"),
+    ("nytimes", False, "ref"),
+    ("pubmed", True, "ref"),
+    ("pubmed", False, "ref"),
+    ("resident", True, "fused"),
+    ("resident", False, "fused"),
+])
+def test_routing_local_only_passes(name, child_stats, path):
+    """A call that reads no child statistics leaves the streamed kernel for
+    the ref route; every other call routes as with child statistics."""
+    import jax
+    from repro.kernels import ops
+    g, k, v, n = ROUTE_SHAPES[name]
+    r = ops.routing(jax.ShapeDtypeStruct((g, k), jnp.float32), None,
+                    (ref.ZChild(jax.ShapeDtypeStruct((k, v), jnp.float32),
+                                None),),
+                    tables="alpha", backend="pallas", n_latent=n,
+                    child_stats=child_stats)
+    assert r.path == path, r
+    if name == "nytimes" and not child_stats:
+        assert "local-only" in r.reason and r.n_tiles == 1
+
+
+# masked flat latents (specialized, strided, two children) and a masked
+# segment latent, each in one chunk and scanned over chunks of 49 tokens
+@pytest.mark.parametrize("chunk", [ref.ZSTATS_CHUNK, 49])
+@pytest.mark.parametrize("case", [3, 5, 7, 9])
+def test_ref_zstats_without_child_stats_bitwise(case, chunk):
+    """child_stats=False drops the child statistics and nothing else: the
+    latent's lse_sum and prior statistics are bitwise those of the full
+    pass."""
+    n, k, gp, cfgs, zm, nz = ZSTATS_CASES[case]
+    et, rows, children, zmask = _zcase(case, n, k, gp, cfgs, zm, nz)
+    assert zmask is not None and any(c.mask is not None for c in children)
+    full = ref.zstats(et, rows, children, zmask, chunk=chunk)
+    local = ref.zstats(et, rows, children, zmask, chunk=chunk,
+                       child_stats=False)
+    assert local[2] == ()
+    _assert_zstats_bitwise(local, full[:2] + ((),))
+
+
+@pytest.mark.parametrize("name", sorted(STREAM_CASES))
+def test_local_only_streamed_call_takes_ref(name, monkeypatch):
+    """Under REPRO_FORCE_PALLAS=1 a child_stats=False call over a streamed
+    table never enters the fused kernel and is bitwise the ref route's
+    lse_sum and prior statistics."""
+    import repro.kernels.fused_zstats as fz
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    et, rows, children, zmask = _zcase(*STREAM_CASES[name])
+    assert ops.routing(et, rows, children, child_stats=False).path == "ref"
+
+    def refuse(*a, **kw):
+        raise AssertionError("local-only pass entered the streamed kernel")
+
+    monkeypatch.setattr(fz, "zstats", refuse)
+    got = ops.zstats(et, rows, children, zmask, child_stats=False)
+    want = ref.zstats(et, rows, children, zmask)
+    _assert_zstats_bitwise(got, want[:2] + ((),))
+    assert got[2] == ()
+
+
+def test_local_only_resident_call_keeps_kernel(monkeypatch):
+    """A resident table has no streamed statistics to save: the local-only
+    call stays on the fused kernel and returns no child statistics."""
+    import repro.kernels.fused_zstats as fz
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    et, rows, children, zmask = _zcase(0, 64, 3, 5,
+                                       [(3, 17, 1, False, False, False)])
+    calls = []
+    orig = fz.zstats
+
+    def spy(*a, **kw):
+        calls.append(1)
+        return orig(*a, **kw)
+
+    monkeypatch.setattr(fz, "zstats", spy)
+    got = ops.zstats(et, rows, children, zmask, child_stats=False)
+    full = ops.zstats(et, rows, children, zmask)
+    assert len(calls) == 2 and got[2] == ()
+    _assert_zstats_bitwise(got, full[:2] + ((),))
+
+
 def test_ops_dispatch_cpu_uses_ref(monkeypatch):
     from repro.kernels import ops
     monkeypatch.delenv("REPRO_FORCE_PALLAS", raising=False)

@@ -1,16 +1,18 @@
 """The streaming minibatch (SVI) engine: exact degenerate cases, padding
 invariance, and held-out ELBO agreement with the full-batch engine."""
 
+import jax
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
 from repro.core import models
+from repro.core.compiler import local_dirichlets, slice_arrays, sliced_shadow
 from repro.core.runtime import make_step
-from repro.core.svi import (SVI, SVIConfig, device_batch, heldout_elbo,
-                            make_svi_step, robbins_monro)
-from repro.core.vmp import init_state
+from repro.core.svi import (SVI, SVIConfig, build_local_scorer, device_batch,
+                            heldout_elbo, make_svi_step, robbins_monro)
+from repro.core.vmp import VMPState, _step_body, init_state
 
 
 def _one_svi_step(prog, state, groups, rho=1.0, scale=1.0, caps_fn=None):
@@ -172,17 +174,109 @@ def test_heldout_elbo_excludes_training_docs(lda_program):
     assert not seen & set(svi.holdout.tolist())
 
 
-def test_slda_minibatch_runs(small_corpus):
-    """The zmap (nested-plate) path under slicing: SLDA minibatches."""
+def _slda_program(small_corpus):
+    """SLDA over the shared corpus, sentences of 7 tokens."""
     n = len(small_corpus["tokens"])
     sent_of_tok = (np.arange(n) // 7).astype(np.int32)
     doc_of_sent = small_corpus["doc_ids"][::7][:sent_of_tok.max() + 1]
     m = models.make("slda", alpha=0.2, beta=0.2, K=3, V=30)
     m["x"].observe(small_corpus["tokens"], segment_ids=sent_of_tok)
     m.bind("sents", doc_of_sent)
-    svi = SVI(m.compile(), SVIConfig(batch_size=8, pad_multiple=32,
-                                     holdout_frac=0.1, holdout_every=5,
-                                     seed=0))
+    return m.compile()
+
+
+def test_slda_minibatch_runs(small_corpus):
+    """The zmap (nested-plate) path under slicing: SLDA minibatches."""
+    svi = SVI(_slda_program(small_corpus),
+              SVIConfig(batch_size=8, pad_multiple=32, holdout_frac=0.1,
+                        holdout_every=5, seed=0))
     state, hist = svi.fit(steps=10)
     assert len(hist["elbo"]) == 10
     assert np.isfinite(hist["heldout"][-1][1])
+
+
+# ---------------------------------------------------------------------------
+# local-only passes: the held-out and fold-in scorer keeps only the locals
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["lda", "slda"])
+def test_step_body_keep_leaves_other_posteriors(name, lda_program,
+                                                small_corpus):
+    """_step_body(keep=...) returns each Dirichlet outside ``keep`` as its
+    input posterior, and the kept posteriors and the ELBO bitwise equal to
+    keep=None (a flat and a segment latent, padded and masked)."""
+    prog = lda_program if name == "lda" else _slda_program(small_corpus)
+    arrays, _, caps, _ = slice_arrays(prog, np.arange(20),
+                                      lambda _, n: -(-n // 32) * 32)
+    shadow = sliced_shadow(prog, caps)
+    local = local_dirichlets(prog)
+    state = init_state(shadow, seed=0)
+    run = jax.jit(lambda st, arr, keep: _step_body(shadow, arr, st,
+                                                   keep=keep),
+                  static_argnums=2)
+    all_new, all_elbo = run(state, arrays, None)
+    new, elbo = run(state, arrays, local)
+    assert local and set(new.posteriors) > local
+    for n, p in new.posteriors.items():
+        want = all_new.posteriors[n] if n in local else state.posteriors[n]
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(want))
+    assert float(elbo) == float(all_elbo)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def _nytimes_lda(docs: int, tokens: int):
+    """An LDA program at the NYTimes widths (K=256, V=102,660) and the
+    shapes of one of its padded batches: ``docs`` documents, ``tokens``
+    token slots.  Posteriors are shapes only; nothing runs."""
+    rng = np.random.default_rng(0)
+    v, k = 102_660, 256
+    m = models.make("lda", alpha=0.1, beta=0.05, K=k, V=v)
+    m["x"].observe(rng.integers(0, v, 2 * docs).astype(np.int32),
+                   segment_ids=np.repeat(np.arange(docs, dtype=np.int32), 2))
+    prog = m.compile()
+    cap = {"theta": docs, "z": tokens, "x": tokens}
+    arrays, dirs, caps, _ = slice_arrays(prog, np.arange(docs),
+                                         lambda name, n: cap[name])
+    posts = {"theta": jax.ShapeDtypeStruct((docs, k), jnp.float32),
+             "phi": jax.ShapeDtypeStruct((k, v), jnp.float32)}
+    return prog, caps, arrays, dirs, posts
+
+
+def test_nytimes_scorer_leaves_streamed_kernel(monkeypatch):
+    """The NYTimes held-out scorer (1,024 documents, 339,712 token slots,
+    10 local passes) under REPRO_FORCE_PALLAS=1: no Pallas kernel and no
+    scatter into the topic table; all 11 token passes run under the
+    ``kernels.zstats.local`` scope.  The training step at the same widths
+    still streams the table through the fused kernel."""
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    table = {(256, 102_660), (102_660, 256)}
+    prog, caps, arrays, _, posts = _nytimes_lda(1024, 339_712)
+    scorer = build_local_scorer(prog, caps, 10)
+    eqns = list(_eqns(jax.make_jaxpr(scorer)(posts, arrays).jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert not [e for e in eqns if e.primitive.name.startswith("scatter")
+                and tuple(e.invars[0].aval.shape) in table]
+    passes = [e for e in eqns if e.primitive.name == "scan"
+              and "kernels.zstats.local" in str(e.source_info.name_stack)]
+    assert len(passes) == 11
+
+    prog, caps, arrays, dirs, posts = _nytimes_lda(512, 169_984)
+    posts["theta"] = jax.ShapeDtypeStruct((prog.dirichlets["theta"].g, 256),
+                                          jnp.float32)
+    step = make_svi_step(prog, caps, donate=False)
+    eqns = list(_eqns(jax.make_jaxpr(step)(
+        VMPState(posts, jax.ShapeDtypeStruct((), jnp.int32)),
+        {"arrays": arrays, "dirs": dirs}, jnp.float32(0.5),
+        jnp.float32(2.0)).jaxpr))
+    assert [e for e in eqns if e.primitive.name == "pallas_call"]
