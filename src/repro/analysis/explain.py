@@ -11,8 +11,8 @@ without tracing a single token or allocating a device buffer:
     exactly);
   - the **kernel route** per latent (ref / fused / fused-streamed /
     fused-zmap, plus the streaming tile layout), computed by
-    :func:`repro.kernels.ops.routing` — the same planner the dispatch
-    asserts against at trace time, so plan and execution cannot drift;
+    :func:`repro.kernels.ops.routing` — the one route decision, which
+    ``ops.zstats`` dispatches on, so plan and execution cannot drift;
   - the **predicted HBM traffic** of the fused vs unfused token-plate
     substep, from the ``docs/performance.md`` model;
   - the **per-host partition** (owned shards/docs/bytes per host) when a

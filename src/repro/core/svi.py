@@ -317,7 +317,7 @@ class _ShardParts:
     multi-process analogue of the ``np.stack`` in :func:`host_batch`'s plan
     path.  In a multi-host run each process materializes only the rows of
     the mesh shards it hosts; :func:`device_put_batch` assembles them into
-    one global array (``launch.shardings.shard_stacked_array``)."""
+    one global array (:func:`shard_stacked_array`)."""
 
     __slots__ = ("shape", "dtype", "parts")
 
@@ -332,6 +332,39 @@ class _ShardParts:
         return sum(p.nbytes for p in self.parts.values())
 
 
+# In a multi-process run no process can jnp.asarray a *global* array — each
+# supplies only the pieces that live on its own devices.  These two builders
+# are the multi-host analogues of the single-process device placement
+# (:func:`device_put_batch`): the replicated one for state and scalars, the
+# stacked one for leading-shard-dim batch arrays.
+
+def replicated_array(mesh, value):
+    """A fully-replicated global ``jax.Array`` over ``mesh`` from a host
+    value.  Every participating process must pass bitwise-identical data —
+    the multi-host engine's inputs are deterministic functions of the
+    shared manifest + seed, so this holds by construction (no collective
+    needed to build it)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    value = np.asarray(value)
+    return jax.make_array_from_callback(
+        value.shape, NamedSharding(mesh, P()), lambda idx: value[idx])
+
+
+def shard_stacked_array(mesh, axes, shape, dtype, parts: dict):
+    """A global array sharded on dim 0 over the mesh ``axes`` from per-shard
+    host rows.  ``shape[0]`` must equal the axes' total size; ``parts`` maps
+    *global* shard index -> that shard's ``shape[1:]`` row, and only this
+    process's shards need be present (the callback is invoked per
+    addressable device, with the global index of its slice)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sharding = NamedSharding(mesh, P(axes))
+
+    def cb(idx):
+        return np.asarray(parts[idx[0].start or 0], dtype)[None]
+
+    return jax.make_array_from_callback(tuple(shape), sharding, cb)
+
+
 def _put_leaf(vv, mesh=None, axes=()):
     """One batch leaf onto the device(s): ``None`` passes through,
     :class:`_ShardParts` becomes a global leading-dim-sharded array over
@@ -342,7 +375,6 @@ def _put_leaf(vv, mesh=None, axes=()):
     if vv is None:
         return None
     if isinstance(vv, _ShardParts):
-        from repro.launch.shardings import shard_stacked_array
         return shard_stacked_array(mesh, axes, vv.shape, vv.dtype, vv.parts)
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -407,7 +439,7 @@ def build_local_scorer(program: VMPProgram, caps: dict[str, int],
     ids dropped).  ``group_elbo.sum()`` equals ``elbo`` up to float
     reassociation.
     """
-    from repro.kernels import ops as kops
+    from repro.kernels import ops as kops, ref as kref
     local = local_dirichlets(program)
     shadow = sliced_shadow(program, caps)
     priors = _priors(program)
@@ -462,7 +494,7 @@ def build_local_scorer(program: VMPProgram, caps: dict[str, int],
         grp = jnp.zeros((n_seg,), jnp.float32)
         for spec in shadow.latents:
             logits = _messages_to_latent(shadow, spec, elog, arrays)
-            _, lse = kops.zstep(logits)
+            _, lse = kref.zstep(logits)
             m = arrays[spec.name].get("mask")
             if m is not None:
                 lse = lse * m
@@ -857,7 +889,6 @@ class SVI:
         in-process, a replicated global array in a multi-process mesh."""
         if not self._multiproc:
             return jnp.float32(x)
-        from repro.launch.shardings import replicated_array
         return replicated_array(self.plan.mesh, np.float32(x))
 
     def _globalize(self, state: VMPState) -> VMPState:
@@ -867,7 +898,6 @@ class SVI:
         so no collective is needed."""
         if not self._multiproc:
             return state
-        from repro.launch.shardings import replicated_array
         mesh = self.plan.mesh
         return VMPState(
             {n: replicated_array(mesh, np.asarray(v))

@@ -239,14 +239,14 @@ def latent_responsibilities(program: VMPProgram, state: VMPState, name: str):
     the step body streams them through ``kops.zstats`` without ever storing
     them, so callers who want q(z) itself pay for it here, on demand.
     """
-    from repro.kernels import ops as kops
+    from repro.kernels import ops as kops, ref as kref
     arrays = _program_arrays(program)
     elog = {n: kops.dirichlet_expectation(p)
             for n, p in state.posteriors.items()}
     for spec in program.latents:
         if spec.name == name:
             logits = _messages_to_latent(program, spec, elog, arrays)
-            r, _ = kops.zstep(logits)
+            r, _ = kref.zstep(logits)
             return r
     raise KeyError(name)
 

@@ -3,7 +3,7 @@
 Two producers:
 
 - :class:`SyntheticCorpus` — a topic-mixture document generator (the paper's
-  LDA-style data: planted topics over a vocabulary, Zipfian doc lengths).
+  LDA-style data: planted topics over a vocabulary, Poisson doc lengths).
   Feeds both the VMP benchmarks (wiki/amazon stand-ins, Table 3) and the
   LDA-driven data-curation example.
 - :class:`TokenStream` — packed LM training batches.  Seekable by step:

@@ -30,8 +30,10 @@ mirrors the exact block structure as the bitwise parity target, and
 
 Budget: all Elog tables, the ``(n_latent, K)`` logits/responsibility
 arrays, and the stats accumulators must be VMEM-resident
-(:func:`fusable_zmap`); combining segment latents with HBM-streamed tables
-falls back to the chunked oracle.
+(``fused_zstats._resident_bytes`` with ``n_latent``, which
+``ops.routing`` compares to the budget: it alone sends a call here);
+combining segment latents with HBM-streamed tables falls back to the
+chunked oracle.
 """
 
 from __future__ import annotations
@@ -44,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dirichlet_expectation import COMPILER_PARAMS
-from .fused_zstats import (_LANE, _TABLE_BUDGET, _block_tokens,
+from .fused_zstats import (_LANE, _block_tokens, _child_dims,
                            _child_message, _child_scatter, _dot,
                            _elog_from_alpha, _layout, _onehot, _pad_to,
                            _zstats_call)
@@ -52,36 +54,13 @@ from .ref import ZChild
 
 
 def _dims(table_prior, children, n_latent: int):
+    """``(k, kp, nzp, child dims)``: the latent's topic count, padded to
+    whole lanes, its padded instance count, and each child's
+    ``(gf, kf, gfp, kfp)``."""
     k = table_prior.shape[1]
     kp = _pad_to(max(k, 1), _LANE)
-    nzp = _pad_to(max(n_latent, 1), _LANE)
-    gpp = _pad_to(max(table_prior.shape[0], 1), _LANE)
-    cdims = []
-    for c in children:
-        gf, kf = c.elog.shape
-        gfp = kp if c.specialized else _pad_to(max(gf, 1), _LANE)
-        cdims.append((gf, kf, gfp, _pad_to(max(kf, 1), _LANE)))
-    return k, kp, nzp, gpp, cdims
-
-
-def fusable_zmap(table_prior, children, tables: str = "elog",
-                 n_latent: int | None = None) -> bool:
-    """True when the two-phase kernel fits: every Elog table, the
-    ``(n_latent, K)`` logits + responsibilities, and the stats accumulators
-    VMEM-resident.  ``n_latent`` is the latent *instance* count
-    (``prior_rows.shape[0]``; ``ops.zstats`` supplies it) — it is not
-    derivable from the tables (SLDA can have far more sentences than its
-    prior has document rows), so an unknown ``n_latent`` answers False
-    rather than risk claiming an over-VMEM layout fits."""
-    if n_latent is None:
-        return False
-    k, kp, nzp, gpp, cdims = _dims(table_prior, children, n_latent)
-    factor = 3 if tables == "alpha" else 2
-    byt = factor * 4 * gpp * kp
-    for (_, _, gfp, kfp) in cdims:
-        byt += factor * 4 * gfp * kfp
-    byt += 4 * 4 * nzp * kp            # logits acc + r (+ pipeline slack)
-    return byt <= _TABLE_BUDGET
+    return (k, kp, _pad_to(max(n_latent, 1), _LANE),
+            _child_dims(children, kp))
 
 
 def _pad_tok(a, np_, fill=0):
@@ -246,15 +225,30 @@ def zstats_zmap(table_prior: jax.Array, prior_rows: jax.Array,
     ``tables`` as in ``fused_zstats.zstats``."""
     if all(c.zmap is None for c in children):
         raise ValueError("no zmap children; use fused_zstats.zstats")
+    return _two_phase(
+        table_prior, prior_rows, children, zmask, tables, block_n,
+        functools.partial(_phase_logits, tables=tables, block_n=block_n,
+                          interpret=interpret),
+        functools.partial(_zstats_call, emit_r=True, interpret=interpret),
+        functools.partial(_phase_stats, block_n=block_n,
+                          interpret=interpret))
+
+
+def _two_phase(table_prior, prior_rows, children, zmask, tables, block_n,
+               logits, latent, stats):
+    """The two-phase substep around its three grids: ``logits(c, k, kp,
+    nzp, cdim)`` of a zmap child, ``latent(layout, extra)`` over the
+    latent plate, ``stats(c, r, k, kp, nzp, cdim)`` of a zmap child — the
+    Pallas kernels, or ``ref.zstats_blocked``'s mirrors of them, which so
+    run this same glue."""
     nz = prior_rows.shape[0]
-    k, kp, nzp, _, cdims = _dims(table_prior, children, nz)
+    k, kp, nzp, cdims = _dims(table_prior, children, nz)
 
     # phase 1: logits accumulated over each zmap child's token plate
     extra = jnp.zeros((nzp, kp), jnp.float32)
     for c, cd in zip(children, cdims):
         if c.zmap is not None:
-            extra = extra + _phase_logits(c, k, kp, nzp, cd, tables,
-                                          block_n, interpret)
+            extra = extra + logits(c, k, kp, nzp, cd)
 
     # phase 2a: latent-plate softmax + prior/non-zmap stats (+ emit r)
     nonz = tuple(c for c in children if c.zmap is None)
@@ -264,13 +258,13 @@ def zstats_zmap(table_prior: jax.Array, prior_rows: jax.Array,
         # a bucketed (streamed-table) latent layout would permute the
         # instances the phase-1 logits and emitted r are matched to
         # positionally — silent corruption, so refuse loudly.  The
-        # fusable_zmap budget keeps ops.zstats off this path.
+        # budget ops.routing applies keeps ops.zstats off this path.
         raise ValueError("segment latents cannot combine with streamed "
                          "tables; use ref.zstats")
     np_lat = lo.nblocks * lo.plan.bn
     ex = extra[:np_lat] if np_lat <= nzp else \
         jnp.pad(extra, ((0, np_lat - nzp), (0, 0)))
-    outs = _zstats_call(lo, extra=ex, emit_r=True, interpret=interpret)
+    outs = latent(lo, ex)
     lse = outs[0].sum()
     pstats = outs[1][:table_prior.shape[0], :k]
     r = outs[-1][:nz]
@@ -285,9 +279,8 @@ def zstats_zmap(table_prior: jax.Array, prior_rows: jax.Array,
         if c.zmap is None:
             cstats.append(next(nonz_stats))
         else:
-            cstats.append(_phase_stats(c, r, k, kp, nzp, cd,
-                                       block_n, interpret))
+            cstats.append(stats(c, r, k, kp, nzp, cd))
     return lse, pstats, tuple(cstats)
 
 
-__all__ = ["zstats_zmap", "fusable_zmap"]
+__all__ = ["zstats_zmap"]

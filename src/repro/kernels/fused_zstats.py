@@ -50,10 +50,12 @@ Implementation notes:
     accumulation is always f32 (tables are upcast after the VMEM load).
 
 Segment latents (a child with a ``zmap``) take the two-phase kernel in
-``kernels/fused_zmap.py``; :func:`fusable` delegates to its budget check.
-The per-block math (:func:`_block_step` and friends) is shared with
-``ref.zstats_blocked``, the block-structured oracle that is the kernels'
-bitwise parity target.
+``kernels/fused_zmap.py``.  Which kernel a call takes is decided in one
+place, ``ops.routing``, from :func:`_plan` and the one footprint formula
+:func:`_resident_bytes`; this module only lays out and runs the route it
+is given.  The per-block math (:func:`_block_step` and friends) is
+shared with ``ref.zstats_blocked``, the block-structured oracle that is
+the kernels' bitwise parity target.
 """
 
 from __future__ import annotations
@@ -80,6 +82,36 @@ _NEG = -1e30
 
 def _pad_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def _child_dims(children, kp: int) -> list:
+    """Per child ``(gf, kf, gfp, kfp)``: its table's shape and the padded
+    shape the table has in VMEM (a specialized child's rows are the
+    ``kp`` padded topics)."""
+    dims = []
+    for c in children:
+        gf, kf = c.elog.shape
+        gfp = kp if c.specialized else _pad_to(max(gf, 1), _LANE)
+        dims.append((gf, kf, gfp, _pad_to(max(kf, 1), _LANE)))
+    return dims
+
+
+def _resident_bytes(table_prior, children, tables: str = "elog",
+                    n_latent: Optional[int] = None) -> int:
+    """Padded f32 bytes of a ``zstats`` call with every table VMEM-resident:
+    each table and its stats accumulator (and its Elog scratch under
+    ``tables="alpha"``), plus, for a segment latent (a child with a
+    ``zmap``) of ``n_latent`` instances, its ``(n_latent, K)`` logits and
+    responsibilities with pipeline slack.  The one footprint formula:
+    :func:`_plan`'s budget test, ``ops.routing``'s ``fused-zmap`` test and
+    ``RouteInfo.table_bytes`` all read it."""
+    kp = _pad_to(max(table_prior.shape[1], 1), _LANE)
+    words = _pad_to(max(table_prior.shape[0], 1), _LANE) * kp
+    words += sum(gfp * kfp for _, _, gfp, kfp in _child_dims(children, kp))
+    byt = (3 if tables == "alpha" else 2) * 4 * words
+    if n_latent is not None and any(c.zmap is not None for c in children):
+        byt += 4 * 4 * _pad_to(max(n_latent, 1), _LANE) * kp
+    return byt
 
 
 def _block_tokens(block_n: Optional[int], *dims: int) -> int:
@@ -234,11 +266,12 @@ def _plan(table_prior, children, tables: str = "elog",
           block_n: Optional[int] = None) -> Optional[_Plan]:
     """Choose the resident/streamed layout, or ``None`` when not fusable.
 
-    Budget accounting is in padded f32 words; every resident table costs
-    table + stats accumulator (+ Elog scratch under ``tables="alpha"``).
-    At most one over-budget table can be streamed, and only along an axis
-    the per-token gather indexes directly: the prior's row axis
+    The budget test reads :func:`_resident_bytes`.  At most one
+    over-budget table can be streamed, and only along an axis the
+    per-token gather indexes directly: the prior's row axis
     (``prior_rows``) or a specialized child's value axis (``values``).
+    ``ops.routing`` is the one caller that turns the answer into a route;
+    :func:`_layout` asks again for the layout of the route it was given.
     """
     if any(c.zmap is not None for c in children):
         return None
@@ -246,30 +279,21 @@ def _plan(table_prior, children, tables: str = "elog",
     kp = _pad_to(max(k, 1), _LANE)
     gp = table_prior.shape[0]
     gpp = _pad_to(max(gp, 1), _LANE)
-    factor = 3 if tables == "alpha" else 2
-
-    child_dims = []
     for c in children:
-        gf, kf = c.elog.shape
-        if c.specialized and gf != k:
-            raise ValueError(f"specialized child table has {gf} rows, "
-                             f"expected K={k}")
-        gfp = kp if c.specialized else _pad_to(max(gf, 1), _LANE)
-        kfp = _pad_to(max(kf, 1), _LANE)
-        child_dims.append((gf, kf, gfp, kfp))
-
-    entries = [("prior", gpp * kp, True)]
-    for ci, (c, (_, _, gfp, kfp)) in enumerate(zip(children, child_dims)):
-        entries.append((ci, gfp * kfp, c.specialized))
-    total = factor * 4 * sum(w for _, w, _ in entries)
+        if c.specialized and c.elog.shape[0] != k:
+            raise ValueError(f"specialized child table has "
+                             f"{c.elog.shape[0]} rows, expected K={k}")
+    child_dims = _child_dims(children, kp)
+    total = _resident_bytes(table_prior, children, tables)
 
     target, tl, n_tiles = None, 0, 1
     if total > _TABLE_BUDGET:
-        cands = [e for e in entries if e[2]]
-        if not cands:
-            return None
+        # the streamable tables, in words: the prior, specialized children
+        cands = [("prior", gpp * kp)] + [
+            (ci, gfp * kfp) for ci, (c, (_, _, gfp, kfp)) in
+            enumerate(zip(children, child_dims)) if c.specialized]
         big = max(cands, key=lambda e: e[1])
-        rest = total - factor * 4 * big[1]
+        rest = total - (3 if tables == "alpha" else 2) * 4 * big[1]
         # tile double-buffer + tiled accumulator + Elog scratch <= 4 tiles
         if rest > _TABLE_BUDGET - 4 * _TILE_BUDGET:
             return None
@@ -393,52 +417,6 @@ def _bucket_host(key: np.ndarray, n: int, tl: int, n_tiles: int, bn: int):
     slot = np.arange(nblocks * bn)
     src = np.where(slot < np.repeat(end, bn), placed, -1).astype(np.int32)
     return src, np.repeat(blk_tile, bn), blk_tile
-
-
-def host_bucketing(table_prior, prior_rows, children, *,
-                   tables: str = "elog", block_n: Optional[int] = None):
-    """Precompute the streamed-table path's token bucketing on the host.
-
-    Returns the ``(src, slot_tile, blk_tile)`` numpy triple that
-    ``zstats(..., bucketing=...)`` consumes, or ``None`` when there is
-    nothing to hoist: the call is not fusable, no table is streamed
-    (resident layout needs no bucketing), or the bucketing key (the prior
-    rows / streamed child's observed values) is a tracer rather than a
-    concrete array.  Only shapes of the tables are inspected, so the
-    *tables* themselves may be tracers — callers inside a jit trace can
-    hoist as long as the observed index streams are trace-time constants
-    (the full-batch engine's case; ``core/vmp.py:_step_body`` caches the
-    result per program)."""
-    if any(c.zmap is not None for c in children):
-        return None
-    plan = _plan(table_prior, children, tables, block_n)
-    if plan is None or plan.target is None:
-        return None
-    key = prior_rows if plan.target == "prior" \
-        else children[plan.target].values
-    if isinstance(key, jax.core.Tracer):
-        return None
-    key = np.asarray(key)
-    return _bucket_host(key, key.shape[0], plan.tl, plan.n_tiles, plan.bn)
-
-
-def fusable(table_prior, children, tables: str = "elog",
-            n_latent: int | None = None) -> bool:
-    """True when the fused kernels support this latent.  Large tables are
-    no longer rejected — one over-budget table is streamed tile-by-tile
-    when the per-token gather indexes it directly (the prior, or a
-    specialized child such as a large-vocabulary LDA ``phi``); segment
-    (zmap) children route to the two-phase ``fused_zmap`` kernel, whose
-    budget check needs ``n_latent`` (the latent instance count,
-    ``prior_rows.shape[0]`` — ``ops.zstats`` supplies it).  What remains
-    unfusable: several over-budget tables at once, an over-budget table
-    only reachable through a strided row computation, or a single row /
-    column wider than a stream tile."""
-    if any(c.zmap is not None for c in children):
-        from .fused_zmap import fusable_zmap
-        return fusable_zmap(table_prior, children, tables,
-                            n_latent=n_latent)
-    return _plan(table_prior, children, tables) is not None
 
 
 # ---------------------------------------------------------------------------
@@ -619,7 +597,7 @@ def _layout(table_prior, prior_rows, children, zmask, *,
         blk_tile = jnp.zeros((np_ // bn,), jnp.int32)
     else:
         if bucketing is not None:
-            # host-precomputed permutation (see host_bucketing): enters the
+            # host-precomputed permutation (ops.host_bucketing): enters the
             # trace as constants, so the per-step device sort disappears
             src, slot_tile, blk_tile = (jnp.asarray(b, jnp.int32)
                                         for b in bucketing)
@@ -628,7 +606,7 @@ def _layout(table_prior, prior_rows, children, zmask, *,
                 raise ValueError(
                     f"stale bucketing: {src.shape[0]} padded slots for a "
                     f"layout that needs {expect} (n={n}, bn={bn}, "
-                    f"tiles={plan.n_tiles}) — recompute host_bucketing "
+                    f"tiles={plan.n_tiles}) — recompute ops.host_bucketing "
                     f"for this program")
             placed = streams[:, jnp.clip(src, 0)]
         else:
@@ -781,15 +759,25 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
     ``dirichlet_expectation`` into the gather.  Tables too large for the
     VMEM budget are streamed tile-by-tile (see the module docstring);
     segment latents (zmap) belong to ``fused_zmap.zstats_zmap``.
-    ``bucketing`` — an optional :func:`host_bucketing` result: the
+    ``bucketing`` — an optional ``ops.host_bucketing`` result: the
     streamed path's token permutation, hoisted out of the trace.
     """
     if any(c.zmap is not None for c in children):
         raise ValueError("segment latents (zmap) take the two-phase "
                          "fused_zmap kernel; use ops.zstats")
+    return _substep(table_prior, prior_rows, children, zmask, tables,
+                    block_n, bucketing,
+                    functools.partial(_zstats_call, interpret=interpret))
+
+
+def _substep(table_prior, prior_rows, children, zmask, tables, block_n,
+             bucketing, grid):
+    """The flat substep around its one grid: lay the call out, run
+    ``grid(layout)`` — the kernel, or ``ref.zstats_blocked``'s mirror of
+    it, which so runs this same glue — and cut the padding off."""
     lo = _layout(table_prior, prior_rows, children, zmask,
                  tables=tables, block_n=block_n, bucketing=bucketing)
-    outs = _zstats_call(lo, interpret=interpret)
+    outs = grid(lo)
     plan = lo.plan
     lse_blocks, pstats = outs[0], outs[1]
     cstats = tuple(cs[:gf, :kf]
@@ -797,5 +785,4 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
     return lse_blocks.sum(), pstats[:plan.gp, :plan.k], cstats
 
 
-__all__ = ["ZChild", "zstats", "fusable", "host_bucketing",
-           "rowsum_digamma"]
+__all__ = ["ZChild", "zstats", "rowsum_digamma"]

@@ -1,9 +1,14 @@
 """Jit'd dispatch layer over the Pallas kernels.
 
-On TPU the Pallas kernels run compiled; everywhere else (this CPU container,
-tests) the pure-jnp oracles from ``ref.py`` are used, except when
+On TPU the Pallas kernels run compiled; everywhere else (the CPU, tests)
+the pure-jnp oracles from ``ref.py`` are used, except when
 ``REPRO_FORCE_PALLAS=1`` forces the kernels through interpret mode (slow but
 exercises the kernel bodies end-to-end).
+
+The token plate's route is chosen in one place: :func:`routing`.
+:func:`zstats` calls it once and runs the implementation its ``path``
+names; EXPLAIN (``analysis/explain.py``) and :func:`host_bucketing` read
+the same answer.
 """
 
 from __future__ import annotations
@@ -13,11 +18,11 @@ import os
 from typing import NamedTuple, Optional
 
 import jax
+import numpy as np
 
 from . import ref
 from .dirichlet_expectation import dirichlet_expectation as _de_pallas
 from .ref import ZChild
-from .vmp_zstep import zstep as _zstep_pallas
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,18 +71,6 @@ def dirichlet_expectation(alpha: jax.Array) -> jax.Array:
     return _de_pallas(alpha, interpret=(b == "pallas_interpret"))
 
 
-def zstep(logits: jax.Array):
-    """Rowwise softmax with its normalizer: ``(r, lse)`` where ``r`` is the
-    ``(N, K)`` float32 responsibilities ``softmax(logits, -1)`` and ``lse``
-    the ``(N,)`` float32 ``logsumexp(logits, -1)`` (each row's exact ELBO
-    contribution at the coordinate optimum).  ``logits`` is ``(N, K)``
-    float32."""
-    b = _backend()
-    if b == "ref" or logits.ndim != 2:
-        return ref.zstep(logits)
-    return _zstep_pallas(logits, interpret=(b == "pallas_interpret"))
-
-
 class RouteInfo(NamedTuple):
     """The kernel-routing decision for one :func:`zstats` call, as pure
     metadata.  ``path`` is what will run:
@@ -90,8 +83,9 @@ class RouteInfo(NamedTuple):
                               ``n_tiles`` describe the streaming layout),
       - ``"fused-zmap"``    — the two-phase segment-latent kernel.
 
-    ``table_bytes`` is the padded-f32 resident footprint the budget check
-    compared against ``budget`` (``_TABLE_BUDGET``); ``table_dtype`` records
+    ``table_bytes`` is the padded-f32 resident footprint
+    (``fused_zstats._resident_bytes``) the budget check compared against
+    ``budget`` (``_TABLE_BUDGET``); ``table_dtype`` records
     the bf16-table mode; ``block_tokens`` the grid block size (0 when not
     applicable); ``reason`` says why this path was chosen in one sentence.
     """
@@ -108,26 +102,6 @@ class RouteInfo(NamedTuple):
     reason: str
 
 
-def _table_bytes(table_prior, children, tables: str,
-                 n_latent: Optional[int]) -> int:
-    """Padded resident footprint (tables + accumulators [+ Elog scratch])
-    in f32 bytes — the quantity the fused kernels' budget checks compare to
-    ``_TABLE_BUDGET``, via the same padding arithmetic."""
-    from .fused_zstats import _LANE, _pad_to
-    k = table_prior.shape[1]
-    kp = _pad_to(max(k, 1), _LANE)
-    gpp = _pad_to(max(table_prior.shape[0], 1), _LANE)
-    factor = 3 if tables == "alpha" else 2
-    byt = factor * 4 * gpp * kp
-    for c in children:
-        gf, kf = c.elog.shape
-        gfp = kp if c.specialized else _pad_to(max(gf, 1), _LANE)
-        byt += factor * 4 * gfp * _pad_to(max(kf, 1), _LANE)
-    if n_latent is not None and any(c.zmap is not None for c in children):
-        byt += 4 * 4 * _pad_to(max(n_latent, 1), _LANE) * kp
-    return byt
-
-
 def routing(table_prior, prior_rows=None, children=(), *,
             tables: str = "elog", backend: Optional[str] = None,
             n_latent: Optional[int] = None,
@@ -140,10 +114,11 @@ def routing(table_prior, prior_rows=None, children=(), *,
     ``jax.ShapeDtypeStruct`` stand-ins, or anything with ``.shape`` (and
     optionally ``.dtype``); ``prior_rows`` supplies ``n_latent`` via its
     leading dim (or pass ``n_latent=`` directly and ``prior_rows=None``).
-    The decision is computed by the *same* planner the kernels use
-    (``fused_zstats._plan`` / ``fused_zmap.fusable_zmap``), and
-    :func:`zstats` asserts agreement at trace time, so this function and
-    the dispatch can never drift.  ``backend`` defaults to this process's
+    This is the only place a route is chosen: :func:`zstats` dispatches
+    on this function's ``path``, so EXPLAIN's prediction and the dispatch
+    are one decision.  It reads ``fused_zstats._plan`` (the streamed
+    layout) and ``fused_zstats._resident_bytes`` (the one footprint
+    formula, also ``table_bytes``).  ``backend`` defaults to this process's
     :func:`_backend` answer; pass ``"pallas"`` to plan for TPU from
     anywhere.  ``child_stats=False`` plans a call whose caller reads no
     child statistics: where the fused kernel would stream a table, such a
@@ -151,14 +126,13 @@ def routing(table_prior, prior_rows=None, children=(), *,
     table), since the kernel would gather and scatter every tile by
     one-hot matmuls for statistics nobody reads.
     """
-    from .fused_zmap import fusable_zmap
-    from .fused_zstats import _TABLE_BUDGET, _plan
+    from .fused_zstats import _TABLE_BUDGET, _plan, _resident_bytes
 
     b = backend if backend is not None else _backend()
     if n_latent is None and prior_rows is not None:
         n_latent = int(prior_rows.shape[0])
     dtype = str(getattr(table_prior, "dtype", "float32"))
-    byt = _table_bytes(table_prior, children, tables, n_latent)
+    byt = _resident_bytes(table_prior, children, tables, n_latent)
 
     def _route(path, target=None, tile=0, n_tiles=1, bn=0, reason=""):
         return RouteInfo(path, b, tables, dtype, target, tile, n_tiles,
@@ -168,7 +142,9 @@ def routing(table_prior, prior_rows=None, children=(), *,
         return _route("ref", reason="ref backend: pure-jnp oracles "
                       "(CPU/GPU default)")
     if any(c.zmap is not None for c in children):
-        if fusable_zmap(table_prior, children, tables, n_latent=n_latent):
+        # n_latent is not derivable from the tables (SLDA has far more
+        # sentences than document rows): unknown, the logits may not fit
+        if n_latent is not None and byt <= _TABLE_BUDGET:
             return _route("fused-zmap",
                           reason="segment latent (zmap child); tables + "
                                  "(n_latent, K) logits fit VMEM")
@@ -204,13 +180,20 @@ def host_bucketing(table_prior, prior_rows, children, *,
     call whose observed index streams are trace-time constants (the
     full-batch engine's arrays).  Returns the numpy triple to pass back as
     ``zstats(..., bucketing=...)``, or ``None`` when there is nothing to
-    hoist (a call that does not take ``fused-streamed``, or traced index
-    streams) — ``None`` is always safe to pass through."""
-    if routing(table_prior, prior_rows, children,
-               tables=tables).path != "fused-streamed":
+    hoist (a call that does not take ``fused-streamed``, or a traced
+    bucketing key) — ``None`` is always safe to pass through.  Built from
+    :func:`routing`'s answer: its target, tile, tile count and block."""
+    route = routing(table_prior, prior_rows, children, tables=tables)
+    if route.path != "fused-streamed":
         return None
-    from .fused_zstats import host_bucketing as _hb
-    return _hb(table_prior, prior_rows, children, tables=tables)
+    key = prior_rows if route.target == "prior" \
+        else children[route.target].values
+    if isinstance(key, jax.core.Tracer):
+        return None
+    from .fused_zstats import _bucket_host
+    key = np.asarray(key)
+    return _bucket_host(key, key.shape[0], route.tile, route.n_tiles,
+                        route.block_tokens)
 
 
 def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
@@ -242,6 +225,7 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
     it replaces never enters the trace.
 
     The hot path of every VMP/SVI iteration (see ``core/vmp.py:_step_body``).
+    The call asks :func:`routing` once and runs what its ``path`` names.
     On TPU the fused Pallas kernels keep responsibilities out of HBM:
 
       - flat latents take ``fused_zstats`` — tables too large for VMEM are
@@ -277,40 +261,23 @@ def zstats(table_prior: jax.Array, prior_rows: jax.Array, children: tuple,
 
 def _zstats(table_prior, prior_rows, children, zmask, tables, bucketing,
             child_stats):
-    b = _backend()
-    if b != "ref":
-        interp = b == "pallas_interpret"
-        # trace-time cross-check: the pure routing() prediction must agree
-        # with the dispatch below (the EXPLAIN plan's accuracy contract)
-        route = routing(table_prior, prior_rows, children, tables=tables,
-                        backend=b, child_stats=child_stats)
-        if any(c.zmap is not None for c in children):
-            from .fused_zmap import fusable_zmap, zstats_zmap
-            if fusable_zmap(table_prior, children, tables,
-                            n_latent=prior_rows.shape[0]):
-                assert route.path == "fused-zmap", route
-                with jax.named_scope("kernels.zstats.segments"):
-                    return _as_asked(zstats_zmap(
-                        table_prior, prior_rows, children, zmask,
-                        tables=tables, interpret=interp), child_stats)
-        else:
-            from .fused_zstats import _plan, zstats as _zstats_pallas
-            plan = _plan(table_prior, children, tables)
-            # a streamed table's statistics are what the kernel's one-hot
-            # matmuls buy; a caller that reads none takes XLA's gathers
-            if plan is not None and (child_stats or plan.target is None):
-                assert route.path in ("fused", "fused-streamed"), route
-                return _as_asked(_zstats_pallas(
-                    table_prior, prior_rows, children, zmask, tables=tables,
-                    interpret=interp, bucketing=bucketing), child_stats)
-        assert route.path == "ref", route
-    return ref.zstats(table_prior, prior_rows, children, zmask,
-                      tables=tables, child_stats=child_stats)
-
-
-def _as_asked(out, child_stats: bool):
-    """A kernel's result as the caller asked for it: ``()`` in place of
-    child statistics it does not read."""
+    route = routing(table_prior, prior_rows, children, tables=tables,
+                    child_stats=child_stats)
+    interp = route.backend == "pallas_interpret"
+    if route.path == "fused-zmap":
+        from .fused_zmap import zstats_zmap
+        with jax.named_scope("kernels.zstats.segments"):
+            out = zstats_zmap(table_prior, prior_rows, children, zmask,
+                              tables=tables, interpret=interp)
+    elif route.path in ("fused", "fused-streamed"):
+        from .fused_zstats import zstats as _zstats_pallas
+        out = _zstats_pallas(table_prior, prior_rows, children, zmask,
+                             tables=tables, interpret=interp,
+                             bucketing=bucketing)
+    else:
+        return ref.zstats(table_prior, prior_rows, children, zmask,
+                          tables=tables, child_stats=child_stats)
+    # a kernel builds every child's statistics: drop those not asked for
     return out if child_stats else (out[0], out[1], ())
 
 
@@ -328,5 +295,5 @@ def flash_attention(q, k, v, *, causal: bool = True):
 
 
 __all__ = ["ZChild", "RouteInfo", "routing", "dirichlet_expectation",
-           "host_bucketing", "zstep", "zstats", "flash_attention",
+           "host_bucketing", "zstats", "flash_attention",
            "reset_backend_cache"]

@@ -7,7 +7,6 @@ fallback on non-TPU backends.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -309,36 +308,44 @@ def _zstats_segmented(elog_prior, prior_rows, children, zmask, chunk, k,
 # block-structured oracle: the Pallas kernels' bitwise parity target
 # ---------------------------------------------------------------------------
 
+def _one_program(fn, *args):
+    """``fn(*args)`` compiled as one XLA program, with the arrays among
+    ``args`` as its parameters and everything else static.  The oracle
+    runs each kernel's grid this way because the interpret-mode kernel
+    runs its grid as one program too.  Run op by op, XLA:CPU computes a
+    dot whose operand is transposed (every one-hot scatter here) as a
+    transpose and then a dot, and the digamma outside its fusion; both
+    sum or round in another order than the compiled grid, 1-2 ulps
+    apart."""
+    flat, tree = jax.tree.flatten(args)
+    dyn = [i for i, x in enumerate(flat) if isinstance(x, jax.Array)]
+
+    def run(*arrays):
+        full = list(flat)
+        for i, a in zip(dyn, arrays):
+            full[i] = a
+        return fn(*jax.tree.unflatten(tree, full))
+
+    return jax.jit(run)(*(flat[i] for i in dyn))
+
+
 def _resolve_table(tab, lane_pad: int, tables: str, dg0=None):
-    """Elog values of one padded table, with the kernels' exact ops.
-
-    Jitted so XLA emits the same fused digamma code it emits for the
-    kernel's in-VMEM computation — eager op-by-op evaluation differs in
-    the last ulp, which would break the bitwise contract."""
-    if tables != "alpha":
-        return tab.astype(jnp.float32)
-    if dg0 is not None:                # streamed along the value axis
-        return _jit_digamma_sub(tab.astype(jnp.float32), dg0)
-    return _jit_elog_from_alpha(tab.astype(jnp.float32), lane_pad)
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _jit_elog_from_alpha(a, lane_pad: int):
-    from .fused_zstats import _elog_from_alpha
-    return _elog_from_alpha(a, lane_pad)
-
-
-@jax.jit
-def _jit_digamma_sub(a, dg0):
+    """Elog values of one padded table, with the kernels' exact ops."""
     from .dirichlet_expectation import _digamma
-    return _digamma(a) - dg0
+    from .fused_zstats import _elog_from_alpha
+    a = tab.astype(jnp.float32)
+    if tables != "alpha":
+        return a
+    if dg0 is not None:                # streamed along the value axis
+        return _digamma(a) - dg0
+    return _elog_from_alpha(a, lane_pad)
 
 
 def _blocked_call(lo, extra=None, emit_r: bool = False):
     """Pure-jnp mirror of ``fused_zstats._zstats_call``: the same blocks in
     the same order with the same one-hot matmuls, accumulated with plain
-    adds.  Returns the raw padded ``[lse_blocks, pstats, *cstats, r?]``."""
-    import jax as _jax
+    adds.  Returns the raw padded ``[lse_blocks, pstats, *cstats, r?]``.
+    Run it through :func:`_one_program`, as the kernel's grid runs."""
     from .fused_zstats import _block_step
     plan, bn = lo.plan, lo.plan.bn
     kp, tl = plan.kp, plan.tl
@@ -360,7 +367,7 @@ def _blocked_call(lo, extra=None, emit_r: bool = False):
         rows = lo.prow[sl]
         if plan.target == "prior":
             ptab = _resolve_table(
-                _jax.lax.dynamic_slice(lo.ptab, (t * tl, 0), (tl, kp)),
+                jax.lax.dynamic_slice(lo.ptab, (t * tl, 0), (tl, kp)),
                 lo.lane_pads[0], plan.mode)
             rows = rows - t * tl
         else:
@@ -370,8 +377,8 @@ def _blocked_call(lo, extra=None, emit_r: bool = False):
             v = lo.cvals[ci][sl]
             if plan.target == ci:
                 tabs.append(_resolve_table(
-                    _jax.lax.dynamic_slice(tab, (0, t * tl),
-                                           (tab.shape[0], tl)),
+                    jax.lax.dynamic_slice(tab, (0, t * tl),
+                                          (tab.shape[0], tl)),
                     lo.lane_pads[1 + ci], plan.mode, dg0=lo.dg0))
                 v = v - t * tl
             else:
@@ -385,16 +392,16 @@ def _blocked_call(lo, extra=None, emit_r: bool = False):
         lse.append(l)
         rs.append(r)
         if plan.target == "prior":
-            cur = _jax.lax.dynamic_slice(pstats, (t * tl, 0), (tl, kp))
-            pstats = _jax.lax.dynamic_update_slice(pstats, cur + pd,
-                                                   (t * tl, 0))
+            cur = jax.lax.dynamic_slice(pstats, (t * tl, 0), (tl, kp))
+            pstats = jax.lax.dynamic_update_slice(pstats, cur + pd,
+                                                  (t * tl, 0))
         else:
             pstats = pstats + pd
         for ci, cd in enumerate(cds):
             if plan.target == ci:
-                cur = _jax.lax.dynamic_slice(
+                cur = jax.lax.dynamic_slice(
                     cstats[ci], (0, t * tl), (cstats[ci].shape[0], tl))
-                cstats[ci] = _jax.lax.dynamic_update_slice(
+                cstats[ci] = jax.lax.dynamic_update_slice(
                     cstats[ci], cur + cd, (0, t * tl))
             else:
                 cstats[ci] = cstats[ci] + cd
@@ -402,6 +409,54 @@ def _blocked_call(lo, extra=None, emit_r: bool = False):
     if emit_r:
         outs.append(jnp.concatenate(rs, axis=0))
     return outs
+
+
+def _blocked_logits(c: ZChild, k: int, kp: int, nzp: int, cdim: tuple,
+                    tables: str, block_n):
+    """Mirror of ``fused_zmap._phase_logits``: one zmap child's message
+    rows, block by block, summed onto its latent instances."""
+    from .fused_zmap import _phase_inputs
+    from .fused_zstats import _child_message, _dot, _onehot
+
+    def grid(inputs, c):
+        bn, tab, vals, zmi, tm, base = inputs
+        tabv = _resolve_table(tab, cdim[3] - cdim[1], tables)
+        zacc = jnp.zeros((nzp, kp), jnp.float32)
+        for b in range(vals.shape[0] // bn):
+            sl = slice(b * bn, (b + 1) * bn)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (bn, kp), 1)
+            e = _child_message(tabv, vals[sl],
+                               None if base is None else base[sl], tm[sl],
+                               k, lane, c.specialized, int(c.stride))
+            zacc = zacc + _dot(_onehot(zmi[sl], nzp).T, e)
+        return zacc
+
+    return _one_program(
+        grid, _phase_inputs(c, kp, nzp, cdim, tables, block_n), c)
+
+
+def _blocked_stats(c: ZChild, r, k: int, kp: int, nzp: int, cdim: tuple,
+                   block_n):
+    """Mirror of ``fused_zmap._phase_stats``: one zmap child's statistics
+    from the latent responsibilities ``r[zmap]``."""
+    from .fused_zmap import _phase_inputs
+    from .fused_zstats import _child_scatter, _dot, _onehot
+    gf, kf, gfp, kfp = cdim
+
+    def grid(inputs, r, c):
+        bn, _, vals, zmi, tm, base = inputs
+        acc = jnp.zeros((gfp, kfp), jnp.float32)
+        for b in range(vals.shape[0] // bn):
+            sl = slice(b * bn, (b + 1) * bn)
+            w = _dot(_onehot(zmi[sl], nzp), r)
+            acc = acc + _child_scatter(
+                w, vals[sl], None if base is None else base[sl], tm[sl],
+                acc.shape, k, c.specialized, int(c.stride))
+        return acc
+
+    return _one_program(
+        grid, _phase_inputs(c, kp, nzp, cdim, "elog", block_n), r, c
+    )[:gf, :kf]
 
 
 def zstats_blocked(table_prior: jax.Array, prior_rows: jax.Array,
@@ -412,83 +467,27 @@ def zstats_blocked(table_prior: jax.Array, prior_rows: jax.Array,
     Replays the kernels' exact tiling, token bucketing, per-block one-hot
     matmuls, and accumulation order in straight-line jnp (no
     ``pallas_call``), so its outputs are **bitwise equal** to the
-    interpret-mode kernels — including the HBM-streamed large-table path,
+    interpret-mode kernels called the same way (both eagerly, as the
+    tests call them) — including the HBM-streamed large-table path,
     the two-phase zmap path, and the ``tables="alpha"`` fused
-    ``dirichlet_expectation``.  This validates the Pallas plumbing
-    (BlockSpecs, scalar-prefetch index maps, scratch accumulators) against
-    plain array code; :func:`zstats` remains the *semantic* oracle the
-    kernels must match within float tolerance.  Lazily imports the shared
-    layout/block helpers (pure jnp) from the kernel modules.
+    ``dirichlet_expectation``.  It runs the kernels' own glue
+    (``fused_zstats._substep``, ``fused_zmap._two_phase``) with each
+    ``pallas_call`` replaced by a mirror of its grid, compiled as one
+    program (:func:`_one_program`, which says why).  This validates the
+    Pallas plumbing (BlockSpecs, scalar-prefetch index maps, scratch
+    accumulators) against plain array code; :func:`zstats` remains the
+    *semantic* oracle the kernels must match within float tolerance.
     """
-    from .fused_zstats import (_child_message, _child_scatter, _dot,
-                               _layout, _onehot)
+    from .fused_zmap import _two_phase
+    from .fused_zstats import _substep
     if not any(c.zmap is not None for c in children):
-        lo = _layout(table_prior, prior_rows, children, zmask,
-                     tables=tables, block_n=block_n)
-        outs = _blocked_call(lo)
-        cstats = tuple(
-            cs[:gf, :kf] for cs, (gf, kf, _, _) in
-            zip(outs[2:], lo.plan.child_dims))
-        return (outs[0].sum(), outs[1][:table_prior.shape[0],
-                                       :lo.plan.k], cstats)
-
-    from .fused_zmap import _dims, _phase_inputs
-    nz = prior_rows.shape[0]
-    k, kp, nzp, _, cdims = _dims(table_prior, children, nz)
-
-    # phase 1: per-block logits accumulation of every zmap child
-    extra = jnp.zeros((nzp, kp), jnp.float32)
-    for c, cd in zip(children, cdims):
-        if c.zmap is None:
-            continue
-        bn, tab, vals, zmi, tm, base = _phase_inputs(c, kp, nzp, cd,
-                                                     tables, block_n)
-        tabv = _resolve_table(tab, cd[3] - cd[1], tables)
-        zacc = jnp.zeros((nzp, kp), jnp.float32)
-        for b in range(vals.shape[0] // bn):
-            sl = slice(b * bn, (b + 1) * bn)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (bn, kp), 1)
-            e = _child_message(tabv, vals[sl],
-                               None if base is None else base[sl],
-                               tm[sl], k, lane, c.specialized,
-                               int(c.stride))
-            oh_z = _onehot(zmi[sl], nzp)
-            zacc = zacc + _dot(oh_z.T, e)
-        extra = extra + zacc
-
-    # phase 2a: latent-plate softmax + prior/non-zmap stats (+ r)
-    nonz = tuple(c for c in children if c.zmap is None)
-    lo = _layout(table_prior, prior_rows, nonz, zmask,
-                 tables=tables, block_n=block_n)
-    if lo.plan.target is not None:     # mirrors fused_zmap.zstats_zmap
-        raise ValueError("segment latents cannot combine with streamed "
-                         "tables; use ref.zstats")
-    np_lat = lo.nblocks * lo.plan.bn
-    ex = extra[:np_lat] if np_lat <= nzp else \
-        jnp.pad(extra, ((0, np_lat - nzp), (0, 0)))
-    outs = _blocked_call(lo, extra=ex, emit_r=True)
-    lse = outs[0].sum()
-    pstats = outs[1][:table_prior.shape[0], :k]
-    r = jnp.pad(outs[-1][:nz], ((0, nzp - nz), (0, 0)))
-
-    # phase 2b: zmap child stats from r[zmap]
-    nonz_stats = iter(cs[:gf, :kf] for cs, (gf, kf, _, _) in
-                      zip(outs[2:-1], lo.plan.child_dims))
-    cstats = []
-    for c, cd in zip(children, cdims):
-        if c.zmap is None:
-            cstats.append(next(nonz_stats))
-            continue
-        gf, kf, gfp, kfp = cd
-        bn, _, vals, zmi, tm, base = _phase_inputs(c, kp, nzp, cd,
-                                                   "elog", block_n)
-        acc = jnp.zeros((gfp, kfp), jnp.float32)
-        for b in range(vals.shape[0] // bn):
-            sl = slice(b * bn, (b + 1) * bn)
-            oh_z = _onehot(zmi[sl], nzp)
-            w = _dot(oh_z, r)
-            acc = acc + _child_scatter(
-                w, vals[sl], None if base is None else base[sl],
-                tm[sl], acc.shape, k, c.specialized, int(c.stride))
-        cstats.append(acc[:gf, :kf])
-    return lse, pstats, tuple(cstats)
+        return _substep(table_prior, prior_rows, children, zmask, tables,
+                        block_n, None,
+                        lambda lo: _one_program(_blocked_call, lo))
+    return _two_phase(
+        table_prior, prior_rows, children, zmask, tables, block_n,
+        lambda c, k, kp, nzp, cdim: _blocked_logits(c, k, kp, nzp, cdim,
+                                                    tables, block_n),
+        lambda lo, extra: _one_program(_blocked_call, lo, extra, True),
+        lambda c, r, k, kp, nzp, cdim: _blocked_stats(c, r, k, kp, nzp,
+                                                      cdim, block_n))

@@ -19,7 +19,6 @@ from __future__ import annotations
 import re
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, RunConfig
@@ -174,33 +173,3 @@ def named(mesh, spec_tree):
         is_leaf=lambda x: isinstance(x, P))
 
 
-# -- multi-host array construction ------------------------------------------
-# In a multi-process run no process can jnp.asarray a *global* array — each
-# supplies only the pieces that live on its own devices.  These two builders
-# are the multi-host analogues of the statistical engines' single-process
-# device placement (``core/svi.py``'s device_put_batch): the replicated one
-# for state/scalars, the stacked one for leading-shard-dim batch arrays.
-
-def replicated_array(mesh, value):
-    """A fully-replicated global ``jax.Array`` over ``mesh`` from a host
-    value.  Every participating process must pass bitwise-identical data —
-    the multi-host engine's inputs are deterministic functions of the
-    shared manifest + seed, so this holds by construction (no collective
-    needed to build it)."""
-    value = np.asarray(value)
-    return jax.make_array_from_callback(
-        value.shape, NamedSharding(mesh, P()), lambda idx: value[idx])
-
-
-def shard_stacked_array(mesh, axes, shape, dtype, parts: dict):
-    """A global array sharded on dim 0 over the mesh ``axes`` from per-shard
-    host rows.  ``shape[0]`` must equal the axes' total size; ``parts`` maps
-    *global* shard index -> that shard's ``shape[1:]`` row, and only this
-    process's shards need be present (the callback is invoked per
-    addressable device, with the global index of its slice)."""
-    sharding = NamedSharding(mesh, P(axes))
-
-    def cb(idx):
-        return np.asarray(parts[idx[0].start or 0], dtype)[None]
-
-    return jax.make_array_from_callback(tuple(shape), sharding, cb)

@@ -8,8 +8,6 @@ roofline analyzer.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -32,57 +30,6 @@ def _install_ctx(mesh):
     from repro.models.sharding_ctx import set_ctx
     from .mesh import data_axes, model_axis
     set_ctx(mesh, data_axes(mesh), model_axis(mesh))
-
-
-def build_infer_step(program, engine="vmp", corpus=None):
-    """Probabilistic-inference analogue of :func:`build_train_step`: build
-    ``(step_fn, state0)`` for a compiled :class:`~repro.core.compiler.VMPProgram`
-    with the backend picked by config — full-batch VMP or streaming SVI
-    (optionally sharded via ``EngineConfig.sharding``).  The result feeds
-    :func:`repro.core.runtime.run_inference` directly, so callbacks and
-    checkpointing work identically across backends.  Gibbs is not a
-    step machine; use ``repro.core.engine.make_engine("gibbs").fit``.
-
-    ``corpus`` (or ``EngineConfig.corpus``) — a
-    :class:`repro.data.ShardedCorpus` for out-of-core SVI: ``program`` may
-    then be an unobserved :class:`~repro.core.dsl.Model` or a template from
-    :func:`repro.data.store.sharded_template`; minibatches stream from the
-    corpus's on-disk shards with double-buffered prefetch.
-    """
-    from repro.core.engine import EngineConfig
-    from repro.core.runtime import make_step
-    from repro.core.svi import SVI, SVIConfig
-    from repro.core.vmp import init_state
-
-    if isinstance(engine, str):
-        engine = EngineConfig(backend=engine)
-    corpus = corpus if corpus is not None else engine.corpus
-    if engine.backend == "vmp":
-        if corpus is not None:
-            raise ValueError("full-batch VMP needs a resident corpus; use "
-                             "engine='svi' for out-of-core inference")
-        if engine.sharding is not None:
-            from repro.core.partition import make_distributed_step
-            return make_distributed_step(program, engine.sharding,
-                                         seed=engine.seed,
-                                         elog_dtype=engine.elog_dtype)
-        return make_step(program, elog_dtype=engine.elog_dtype), \
-            init_state(program, engine.seed)
-    if engine.backend == "svi":
-        svi = SVI(program, SVIConfig(
-            batch_size=engine.batch_size, kappa=engine.kappa, tau=engine.tau,
-            local_iters=engine.local_iters, pad_multiple=engine.pad_multiple,
-            holdout_frac=engine.holdout_frac,
-            holdout_every=engine.holdout_every, seed=engine.seed,
-            elog_dtype=engine.elog_dtype),
-            plan=engine.sharding, corpus=corpus, hosts=engine.hosts)
-
-        def step_fn(state):
-            return svi.step(int(state.step), state)
-
-        step_fn.svi = svi                   # heldout_elbo / sampler access
-        return step_fn, init_state(svi.program, engine.seed)
-    raise ValueError(f"no step builder for backend {engine.backend!r}")
 
 
 def build_train_step(cfg: ArchConfig, run: RunConfig, mesh):

@@ -124,11 +124,55 @@ def test_vmp_engine_with_holdout_matches_plain_vmp_topics(lda_model,
     assert np.isfinite(r_hold.heldout_elbo)
 
 
+_INFERENCE_IMPORTS = """
+import sys; sys.path.insert(0, {src!r})
+import jax
+import numpy as np
+import repro.core.svi, repro.query, repro.gateway
+from repro.core import EngineConfig, models
+from repro.core.runtime import build_infer_step
+from repro.core.svi import replicated_array, shard_stacked_array
+from repro.data import SyntheticCorpus
+c = SyntheticCorpus(n_docs=20, vocab=30, n_topics=3, mean_len=20,
+                    seed=0).generate()
+m = models.make("lda", alpha=0.1, beta=0.05, K=3, V=30)
+m["x"].observe(c["tokens"], segment_ids=c["doc_ids"])
+step, state = build_infer_step(m.compile(),
+                               EngineConfig(backend="svi", batch_size=8))
+state, elbo = step(state)
+mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("d",))
+rep = replicated_array(mesh, np.arange(3.0))
+stk = shard_stacked_array(mesh, "d", (1, 2), np.float32,
+                          {{0: np.ones(2, np.float32)}})
+assert np.isfinite(float(elbo))
+assert rep.tolist() == [0.0, 1.0, 2.0] and stk.tolist() == [[1.0, 1.0]]
+lm = sorted(n for n in sys.modules
+            if n.split(".")[:2] in (["repro", "models"], ["repro", "configs"],
+                                    ["repro", "optim"], ["repro", "launch"]))
+print("LM", lm)
+"""
+
+
+def test_inference_path_imports_no_lm_stack():
+    """The SVI engine, query and gateway packages, ``build_infer_step``
+    and the multi-host array builders, imported and run in a fresh
+    interpreter, load nothing of the seed transformer-LM stack
+    (``repro.models``, ``repro.configs``, ``repro.optim``) or of
+    ``repro.launch``."""
+    import os
+
+    from repro.testing import faults
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    r = faults.run_child(_INFERENCE_IMPORTS.format(src=src), timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    assert "LM []" in r.stdout, r.stdout
+
+
 def test_build_infer_step_selects_backend(lda_program):
-    """launch.steps.build_infer_step: both step-machine backends drive
+    """core.runtime.build_infer_step: both step-machine backends drive
     run_inference (callbacks, checkpointing) interchangeably."""
-    from repro.core.runtime import run_inference
-    from repro.launch.steps import build_infer_step
+    from repro.core.runtime import build_infer_step, run_inference
 
     for engine in ("vmp", EngineConfig(backend="svi", batch_size=16,
                                        pad_multiple=32)):

@@ -9,7 +9,6 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.dirichlet_expectation import dirichlet_expectation as de_pallas
-from repro.kernels.vmp_zstep import zstep as zstep_pallas
 
 SHAPES = [(1, 2), (3, 5), (7, 128), (33, 96), (128, 130), (257, 4),
           (64, 300), (1000, 3)]
@@ -23,16 +22,6 @@ def test_dirichlet_expectation_allclose(shape, dtype):
     got = de_pallas(a, interpret=True)
     want = ref.dirichlet_expectation(a)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("shape", SHAPES)
-def test_zstep_allclose(shape):
-    rng = np.random.default_rng(hash(shape) % 2**31)
-    x = jnp.asarray(rng.normal(size=shape).astype(np.float32) * 4)
-    r_g, l_g = zstep_pallas(x, interpret=True)
-    r_w, l_w = ref.zstep(x)
-    np.testing.assert_allclose(r_g, r_w, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(l_g, l_w, rtol=1e-5, atol=1e-5)
 
 
 FLASH_SHAPES = [(1, 32, 16, 16, 16), (2, 64, 16, 16, 32), (1, 100, 32, 32, 32),
@@ -446,12 +435,13 @@ def test_plan_rejects_tiles_wider_than_budget():
     (ShapeDtypeStructs) — these tables would be GBs if materialized."""
     import jax
     import repro.kernels.fused_zstats as fz
+    from repro.kernels import ops
     # specialized child, K=8192 topics: one 128-column tile is 4 MiB
     tp = jax.ShapeDtypeStruct((16, 8192), jnp.float32)
     big = ref.ZChild(jax.ShapeDtypeStruct((8192, 40000), jnp.float32),
                      values=None)
     assert fz._plan(tp, (big,)) is None
-    assert not fz.fusable(tp, (big,))
+    assert ops.routing(tp, None, (big,), backend="pallas").path == "ref"
     # streamed-prior flavor: K=70000 lanes, one 8-row tile is >2 MiB
     tp = jax.ShapeDtypeStruct((100000, 70000), jnp.float32)
     small = ref.ZChild(jax.ShapeDtypeStruct((10, 5), jnp.float32),
@@ -461,21 +451,23 @@ def test_plan_rejects_tiles_wider_than_budget():
 
 def test_fusable_zmap_requires_n_latent():
     """The (n_latent, K) budget is not derivable from the tables (SLDA can
-    have far more sentences than its prior has rows), so an unknown
-    n_latent must answer False — never claim an over-VMEM layout fits."""
-    from repro.kernels.fused_zmap import fusable_zmap
+    have far more sentences than its prior has rows), so routing with an
+    unknown n_latent must answer ``ref`` — never claim an over-VMEM
+    layout fits."""
+    from repro.kernels import ops
     ch = (ref.ZChild(jnp.zeros((3, 5), jnp.float32),
                      jnp.zeros((4,), jnp.int32), 1,
                      zmap=jnp.zeros((4,), jnp.int32)),)
     tp = jnp.zeros((10, 3), jnp.float32)
-    assert not fusable_zmap(tp, ch)
-    assert fusable_zmap(tp, ch, n_latent=4)
+    assert ops.routing(tp, None, ch, backend="pallas").path == "ref"
+    assert ops.routing(tp, None, ch, backend="pallas",
+                       n_latent=4).path == "fused-zmap"
 
 
 def test_zmap_kernel_refuses_streamed_prior():
     """zstats_zmap matches phase-1 logits and the emitted r to latent
     instances positionally, which a bucketed (streamed-table) latent
-    layout would permute: direct calls past the fusable_zmap gate must
+    layout would permute: direct calls past routing's budget must
     raise, not silently corrupt."""
     import repro.kernels.fused_zmap as fzm
     rng = np.random.default_rng(0)
@@ -592,6 +584,81 @@ def test_local_only_resident_call_keeps_kernel(monkeypatch):
     _assert_zstats_bitwise(got, full[:2] + ((),))
 
 
+# ---------------------------------------------------------------------------
+# dispatch: ops.zstats runs what ops.routing names
+# ---------------------------------------------------------------------------
+
+# name -> (_zcase args, child_stats, the route routing must give)
+DISPATCH_CASES = {
+    # a strided child over budget: no table the kernel can stream
+    "ref": ((7, 300, 4, 20, [(70000, 33, 3, True, False, False)], False,
+             None), True, "ref"),
+    "fused": ((0, 64, 3, 5, [(3, 17, 1, False, False, False)], False, None),
+              True, "fused"),
+    "fused-streamed": (STREAM_CASES["child"], True, "fused-streamed"),
+    "fused-zmap": ((1000,) + ZMAP_KERNEL_CASES[0], True, "fused-zmap"),
+    "local-only-streamed": (STREAM_CASES["prior"], False, "ref"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DISPATCH_CASES))
+def test_dispatch_follows_routing(name, monkeypatch):
+    """Under REPRO_FORCE_PALLAS=1, ``ops.zstats`` runs exactly the one
+    implementation ``ops.routing``'s ``path`` names (the fused kernel
+    streaming a table exactly when the route is ``fused-streamed``), and
+    returns child statistics only when asked."""
+    import repro.kernels.fused_zmap as fzm
+    import repro.kernels.fused_zstats as fz
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    args, child_stats, path = DISPATCH_CASES[name]
+    et, rows, children, zmask = _zcase(*args)
+    assert ops.routing(et, rows, children,
+                       child_stats=child_stats).path == path
+
+    ran = []
+
+    def spy(mod, fn, label):
+        orig = getattr(mod, fn)
+
+        def run(*a, **kw):
+            ran.append(label(*a, **kw))
+            return orig(*a, **kw)
+
+        monkeypatch.setattr(mod, fn, run)
+
+    spy(ref, "zstats", lambda *a, **kw: "ref")
+    spy(fz, "zstats", lambda tp, rows, ch, *a, **kw:
+        "fused" if fz._plan(tp, ch, kw.get("tables", "elog")).target is None
+        else "fused-streamed")
+    spy(fzm, "zstats_zmap", lambda *a, **kw: "fused-zmap")
+    got = ops.zstats(et, rows, children, zmask, child_stats=child_stats)
+    assert ran == [path]
+    assert len(got[2]) == (len(children) if child_stats else 0)
+
+
+# every ZSTATS case and every fused-expectation case: elog and alpha
+# tables, resident, strided, multi-child, streamed and zmap layouts
+BLOCKED_CASES = {
+    **{f"elog-{i}": ((i,) + c, "elog") for i, c in enumerate(ZSTATS_CASES)},
+    **{f"alpha-{name}": (args, "alpha") for name, args in ALPHA_CASES},
+}
+
+
+@pytest.mark.parametrize("name", sorted(BLOCKED_CASES))
+def test_kernel_matches_blocked_oracle_bitwise(name, monkeypatch):
+    """The interpret-mode kernels equal ``ref.zstats_blocked`` bitwise,
+    both called eagerly: the oracle replays each kernel's grid as one
+    compiled program."""
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    args, tables = BLOCKED_CASES[name]
+    case = _gamma_case(args) if tables == "alpha" else _zcase(*args)
+    assert ops.routing(*case[:3], tables=tables).path != "ref"
+    _assert_zstats_bitwise(ops.zstats(*case, tables=tables),
+                           ref.zstats_blocked(*case, tables=tables))
+
+
 def test_ops_dispatch_cpu_uses_ref(monkeypatch):
     from repro.kernels import ops
     monkeypatch.delenv("REPRO_FORCE_PALLAS", raising=False)
@@ -609,9 +676,6 @@ def test_ops_dispatch_forced_pallas(monkeypatch):
     np.testing.assert_allclose(ops.dirichlet_expectation(a),
                                ref.dirichlet_expectation(a),
                                rtol=2e-4, atol=2e-4)
-    r, l = ops.zstep(a)
-    rr, ll = ref.zstep(a)
-    np.testing.assert_allclose(r, rr, rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -657,22 +721,24 @@ def test_host_bucketing_streamed_zstats_bitwise(name, monkeypatch):
             interpret=True, bucketing=bucketing)
 
 
-def test_host_bucketing_none_for_resident_and_traced():
+def test_host_bucketing_none_for_resident_and_traced(monkeypatch):
     """Nothing to hoist: resident layouts and traced index streams both
     answer None (always safe to pass through)."""
     import jax
-    from repro.kernels import fused_zstats as fz
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
     et, rows, children, zmask = _zcase(20, 300, 4, 20,
                                        [(4, 33, 1, False, False, False)])
-    assert fz.host_bucketing(et, rows, children) is None   # resident
+    assert ops.host_bucketing(et, rows, children) is None  # resident
 
     et_s, rows_s, children_s, _ = _zcase(*STREAM_CASES["prior"])
+    assert ops.host_bucketing(et_s, rows_s, children_s) is not None
 
     got = []
 
     @jax.jit
     def probe(r):
-        got.append(fz.host_bucketing(et_s, r, children_s))
+        got.append(ops.host_bucketing(et_s, r, children_s))
         return r
 
     probe(rows_s)
@@ -875,9 +941,13 @@ def test_layout_matches_numpy_bitwise(name):
                          None if zm is None else np.asarray(zm),
                          lo.plan, tables)
     _assert_layout_equal(lo, want)
-    hb = fz.host_bucketing(et, rows, children, tables=tables, block_n=128)
-    assert (hb is None) == (lo.plan.target is None)
-    if hb is not None:
+    # the hoisted bucketing, built from the plan in hand as
+    # ops.host_bucketing builds it from the route
+    if lo.plan.target is not None:
+        key = np.asarray(rows if lo.plan.target == "prior"
+                         else children[lo.plan.target].values)
+        hb = fz._bucket_host(key, key.shape[0], lo.plan.tl,
+                             lo.plan.n_tiles, lo.plan.bn)
         _assert_layout_equal(
             fz._layout(et, rows, children, zm, tables=tables, block_n=128,
                        bucketing=hb), want)

@@ -342,28 +342,35 @@ def test_two_process_bitwise_equals_virtual(corpus_dir, tmp_path, gloo2):
 # regression (partitioning, caps agreement, psum wiring) shows up as a
 # trajectory shift even on a machine with no second topology to diff
 # against.  Loose tolerance absorbs BLAS/platform float noise; the
-# *cross*-topology agreements asserted alongside are much tighter.
+# *cross*-topology agreements asserted alongside are much tighter.  The
+# trajectory was drawn with jax's original threefry key derivation; since
+# jax 0.5 ``jax_threefry_partitionable`` defaults to True, which gives
+# ``init_state``'s noise other bits (-2.3685 at step 3), so the test pins
+# the derivation the constants were drawn with, in the child too.
 _GOLDEN_ENGINE = dict(backend="svi", steps=8, batch_size=12,
                       holdout_frac=0.1, holdout_every=4, seed=0)
 _GOLDEN_HELDOUT = [(3, -2.4281643107786017), (7, -2.444341523768538)]
 
 
-def test_cross_topology_heldout_golden(corpus_dir, tmp_path):
+def test_cross_topology_heldout_golden(corpus_dir, tmp_path, monkeypatch):
     """One schedule, three topologies: resident, sharded-corpus, and
     2-virtual-host runs of the same fixed-seed fit.  Resident and sharded
     must agree *bitwise* (same process, same program); the 2-virtual-host
     heldout trajectory agrees to float-reassociation tolerance; and all
     of them match the committed golden trajectory."""
+    import jax
     from repro.core import models
     from repro.core.engine import make_engine
+    monkeypatch.setenv("JAX_THREEFRY_PARTITIONABLE", "0")  # the child's
     corpus = SyntheticCorpus(n_docs=60, vocab=30, n_topics=3, mean_len=50,
                              seed=0).generate()
     m = models.make("lda", alpha=0.1, beta=0.05, K=3, V=30)
     m["x"].observe(corpus["tokens"], segment_ids=corpus["doc_ids"])
-    res = make_engine(dict(_GOLDEN_ENGINE)).fit(m)
-    sh = make_engine(dict(_GOLDEN_ENGINE),
-                     corpus=ShardedCorpus.open(corpus_dir)).fit(
-        models.make("lda", alpha=0.1, beta=0.05, K=3, V=30))
+    with jax.threefry_partitionable(False):      # see _GOLDEN_HELDOUT
+        res = make_engine(dict(_GOLDEN_ENGINE)).fit(m)
+        sh = make_engine(dict(_GOLDEN_ENGINE),
+                         corpus=ShardedCorpus.open(corpus_dir)).fit(
+            models.make("lda", alpha=0.1, beta=0.05, K=3, V=30))
     assert res.elbo_trace == sh.elbo_trace
     assert res.heldout_trace == sh.heldout_trace
     out = str(tmp_path / "v2.npz")

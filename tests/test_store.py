@@ -285,7 +285,7 @@ def test_engine_api_out_of_core(store):
 
 def test_build_infer_step_out_of_core(store):
     from repro.core.engine import EngineConfig
-    from repro.launch.steps import build_infer_step
+    from repro.core.runtime import build_infer_step
     step_fn, state = build_infer_step(
         _lda(), EngineConfig(backend="svi", batch_size=16, seed=0),
         corpus=ShardedCorpus.open(store.path))

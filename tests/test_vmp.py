@@ -8,6 +8,7 @@ from jax.scipy.special import digamma, gammaln
 from repro.core import models
 from repro.core.vmp import init_state
 
+import jax
 import jax.numpy as jnp
 
 
@@ -78,8 +79,14 @@ def test_lda_matches_handwritten_cavi():
 # ELBO traces captured from the pre-fusion step body (gather -> zstep ->
 # segment_sum, commit 9c7e323) on fixed seeds: the fused zstats
 # restructuring must reproduce them.  On the single-chunk path the stats
-# scatters are the same primitives in the same order, so the match is
+# scatters are the same primitives in the same order, so the match was
 # bitwise; the assertion allows float32 headroom for future re-chunking.
+# They were drawn with jax's original threefry key derivation: since jax
+# 0.5 ``jax_threefry_partitionable`` defaults to True, which gives
+# ``init_state``'s uniform noise other bits (the traces then differ by up
+# to 12% for LDA and 1.3% for SLDA from step 0).  The tests pin the
+# derivation the constants were drawn with; nothing else changed them
+# (under it they agree to 6.7e-7 and 3.4e-7 relative).
 _GOLD_LDA = [-13043.072265625, -7396.16015625, -7368.0078125,
              -7311.3955078125, -7188.77294921875, -6974.115234375,
              -6709.36083984375, -6444.1083984375, -6165.09423828125,
@@ -97,7 +104,8 @@ def test_fused_step_reproduces_prefusion_elbo_trace():
                         seed=0).generate()
     m = models.make("lda", alpha=0.1, beta=0.05, K=3, V=30)
     m["x"].observe(c["tokens"], segment_ids=c["doc_ids"])
-    m.infer(steps=10, seed=0)
+    with jax.threefry_partitionable(False):      # see _GOLD_LDA
+        m.infer(steps=10, seed=0)
     np.testing.assert_allclose(m.elbo_trace, _GOLD_LDA, rtol=1e-6)
     scale = abs(_GOLD_LDA[0])
     assert (np.diff(m.elbo_trace) >= -1e-6 * scale).all()
@@ -114,7 +122,8 @@ def test_fused_step_reproduces_prefusion_elbo_trace_segmented():
     m = models.make("slda", alpha=0.2, beta=0.2, K=3, V=20)
     m["x"].observe(xs, segment_ids=tok_sent)
     m.bind("sents", sent_doc)
-    m.infer(steps=8, seed=0)
+    with jax.threefry_partitionable(False):      # see _GOLD_LDA
+        m.infer(steps=8, seed=0)
     np.testing.assert_allclose(m.elbo_trace, _GOLD_SLDA, rtol=1e-6)
 
 
