@@ -6,7 +6,8 @@ Covers:
   2. VMP communication: inferspark layout all-reduces only the global
      Dirichlets (theta stats move zero bytes)
   3. out-of-core SVI (disk-sharded corpus) under a ShardingPlan is bitwise
-     the resident sharded-plan run
+     the resident sharded-plan run; SVI.fit's staged inputs under a
+     ShardingPlan give the plain step loop's history and state bitwise
   4. LM train step on a (4 data, 2 model) mesh: runs + loss finite
   5. elastic re-mesh: checkpoint on 8 devices, resume on 4, loss continues
   6. long-context decode: batch=1 cache sharded over the sequence axis
@@ -110,6 +111,42 @@ def check_svi_outofcore_parity(tmp="/tmp/repro_dist_shards"):
     print("PASS svi_outofcore_parity")
 
 
+def check_svi_staging_plan():
+    """Under a ShardingPlan, fit() (step t+1's batch put on the mesh while
+    step t runs) gives the plain loop's history and state bit for bit:
+    step(t), float(elbo), held-out score, one after the other."""
+    from repro.core.svi import SVI, SVIConfig
+    from repro.core.vmp import init_state
+    from repro.data import SyntheticCorpus
+    corpus = SyntheticCorpus(n_docs=48, vocab=50, n_topics=4, mean_len=60,
+                             seed=5).generate()
+    plan = ShardingPlan(make_mesh((8,), ("data",)), ("data",), "inferspark")
+    cfg = SVIConfig(batch_size=16, holdout_frac=0.1, holdout_every=3,
+                    pad_multiple=64, seed=0)
+
+    def engine():
+        m = models.make("lda", alpha=0.1, beta=0.1, K=4, V=50)
+        m["x"].observe(corpus["tokens"], segment_ids=corpus["doc_ids"])
+        return SVI(m.compile(), cfg, plan=plan)
+
+    svi = engine()
+    state, hist = svi.fit(steps=7)
+    assert (svi.staged_used, svi.staged_dropped) == (7, 1)
+    plain = engine()
+    want_state = init_state(plain.program, 0)
+    want = {"elbo": [], "heldout": []}
+    for t in range(7):
+        want_state, elbo = plain.step(t, want_state)
+        want["elbo"].append(float(elbo))
+        if (t + 1) % 3 == 0 or t == 6:
+            want["heldout"].append((t, plain.heldout_elbo(want_state)))
+    assert hist == want, (hist, want)
+    for name in state.posteriors:
+        np.testing.assert_array_equal(np.asarray(state.posteriors[name]),
+                                      np.asarray(want_state.posteriors[name]))
+    print("PASS svi_staging_plan")
+
+
 def check_vmp_collectives():
     K, V, D = 4, 40, 30
     doc_len = rng.integers(10, 80, size=D)
@@ -209,6 +246,7 @@ if __name__ == "__main__":
     check_vmp_parity()
     check_svi_distributed_parity()
     check_svi_outofcore_parity()
+    check_svi_staging_plan()
     check_vmp_collectives()
     check_lm_train_2d_mesh()
     check_elastic_remesh()

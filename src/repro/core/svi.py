@@ -727,6 +727,12 @@ class SVI:
             self._weights = self._group_token_weights()
         self._steps: dict = {}
         self._heldout_cache: dict = {}
+        # (t, (batch, caps, rho, scale) or None, staging error or None):
+        # the next step's inputs, staged by fit() while the current one
+        # runs (the first step's before it is dispatched)
+        self._staged: Optional[tuple] = None
+        self.staged_used = 0           # steps dispatched from staged inputs
+        self.staged_dropped = 0        # staged inputs no step consumed
 
     def _group_token_weights(self) -> np.ndarray:
         """Per-group observed-token counts ``(pstar_size,) int64`` — the
@@ -885,10 +891,12 @@ class SVI:
         return {"arrays": arrays, "dirs": dirs}, caps, n_tok, len(groups)
 
     def _scalar(self, x):
-        """A step scalar every mesh shard can read: plain ``jnp.float32``
-        in-process, a replicated global array in a multi-process mesh."""
+        """A step scalar every mesh shard can read: a ``float32`` copied to
+        the default device in-process (the aval of ``jnp.float32(x)``, with
+        no conversion program), a replicated global array in a
+        multi-process mesh."""
         if not self._multiproc:
-            return jnp.float32(x)
+            return jax.device_put(np.float32(x))
         return replicated_array(self.plan.mesh, np.float32(x))
 
     def _globalize(self, state: VMPState) -> VMPState:
@@ -904,38 +912,66 @@ class SVI:
              for n, v in state.posteriors.items()},
             replicated_array(mesh, np.asarray(state.step, np.int32)))
 
-    def step(self, t: int, state: VMPState):
-        """One SVI step at schedule position ``t``; returns (state', elbo).
+    def _stage(self, t: int) -> tuple:
+        """Step ``t``'s inputs on the device: ``(batch, caps, rho, scale)``.
 
-        Host spans (profiler trace only): ``svi.host_batch``,
-        ``svi.device_put``, ``svi.dispatch`` (``svi.compile`` inside it on
-        a new step signature, which also holds that first call)."""
+        Host spans (profiler trace only): ``svi.host_batch`` and
+        ``svi.device_put`` (the batch and both step scalars)."""
         with TraceAnnotation("svi.host_batch"):
             if self.corpus is not None:
                 hb, caps, _, n_b = self.sampler.host_batch_at(t)
             else:
                 hb, caps, _, n_b = self._load_groups(
                     self.sampler.batch_at(t))
+        rho = (self.cfg.rho if self.cfg.rho is not None
+               else robbins_monro(t, self.cfg.tau, self.cfg.kappa))
+        # n_b is the true batch size (the epoch's tail batch may be
+        # short).  The stochastic scale G/|B|: G is the training
+        # population — fixed in batch mode, the epoch snapshot size under
+        # a growing corpus, or a pinned assumed population (population-VI)
+        # for unbounded streams.  Traced as a scalar either way: growth
+        # never retraces.
+        if self.cfg.growing:
+            n_pop = self.cfg.population_size or self.sampler.population_at(t)
+        else:
+            n_pop = len(self.train)
         with TraceAnnotation("svi.device_put"):
             batch = device_put_batch(
                 hb, mesh=self.plan.mesh if self.plan is not None else None,
                 axes=self.plan.axes if self.plan is not None else ())
+            return batch, caps, self._scalar(rho), self._scalar(n_pop / n_b)
+
+    def _stage_ahead(self, t: int) -> None:
+        """Stage step ``t``'s inputs for the next :meth:`step` call.  A
+        failure is kept and raised by the ``step(t)`` that would have
+        staged them, as the sampler's prefetcher re-raises at its ``get``."""
+        try:
+            self._staged = (t, self._stage(t), None)
+        except Exception as e:                # raised by step(t)
+            self._staged = (t, None, e)
+
+    def step(self, t: int, state: VMPState):
+        """One SVI step at schedule position ``t``; returns (state', elbo).
+
+        Dispatches from the inputs :meth:`fit` staged for ``t``
+        (``staged_used``); without them it stages them first
+        (:meth:`_stage`), and drops inputs staged for another step
+        (``staged_dropped``).  Host span ``svi.dispatch`` (``svi.compile``
+        inside it on a new step signature, which also holds that first
+        call)."""
+        staged, self._staged = self._staged, None
+        if staged is not None and staged[0] == t:
+            if staged[2] is not None:
+                raise staged[2]
+            self.staged_used += 1
+            inputs = staged[1]
+        else:
+            if staged is not None:
+                self.staged_dropped += 1
+            inputs = self._stage(t)
+        batch, caps, rho, scale = inputs
         with TraceAnnotation("svi.dispatch"):
-            rho = (self.cfg.rho if self.cfg.rho is not None
-                   else robbins_monro(t, self.cfg.tau, self.cfg.kappa))
-            # n_b is the true batch size (the epoch's tail batch may be
-            # short).  The stochastic scale G/|B|: G is the training
-            # population — fixed in batch mode, the epoch snapshot size
-            # under a growing corpus, or a pinned assumed population
-            # (population-VI) for unbounded streams.  Traced as a scalar
-            # either way: growth never retraces.
-            if self.cfg.growing:
-                n_pop = (self.cfg.population_size
-                         or self.sampler.population_at(t))
-            else:
-                n_pop = len(self.train)
-            args = (state, batch, self._scalar(rho),
-                    self._scalar(n_pop / n_b))
+            args = (state, batch, rho, scale)
             sig = tuple(sorted(caps.items()))
             fn = self._steps.get(sig)
             if fn is None:
@@ -1090,9 +1126,17 @@ class SVI:
         always-on loop can use one code path.  ``steps`` counts the
         updates *this call* runs (on resume: the remaining budget).
 
-        Under a profiler session each iteration is a ``svi.step`` step
-        span holding ``svi.elbo_sync`` and ``svi.heldout`` (see "Tracing a
-        fit" in ``docs/inference_engines.md``).
+        The first step's inputs are staged before its iteration; each
+        iteration then dispatches step t, stages step t+1's inputs on the
+        device while step t runs (``svi.stage``), and waits for step t's
+        ELBO: the same programs on the same inputs in the same order as
+        staging nothing.  Inputs staged for a step this call does not run
+        (the one past its last step, a stop, an error) are dropped when it
+        returns, and a failure to stage them is never raised.  Under a
+        profiler session each iteration is a ``svi.step`` step span holding
+        one ``svi.dispatch``, ``svi.stage`` and ``svi.elbo_sync``, and
+        ``svi.heldout`` where it scores (see "Tracing a fit" in
+        ``docs/inference_engines.md``).
         """
         from repro.checkpoint import CheckpointStore
         from repro.checkpoint import session as _session
@@ -1134,10 +1178,19 @@ class SVI:
         state = self._globalize(state)
         start = int(state.step)
         try:
+            if steps > 0:
+                with TraceAnnotation("svi.stage"):
+                    self._stage_ahead(start)
             for t in range(start, start + steps):
                 with StepTraceAnnotation("svi.step", step_num=t):
                     faults.trip("svi.step")
                     state, elbo = self.step(t, state)
+                    # step t+1's inputs do not depend on step t: put them
+                    # on the device while step t runs.  The last step
+                    # stages too, so every step span holds one staging;
+                    # what it stages is dropped below.
+                    with TraceAnnotation("svi.stage"):
+                        self._stage_ahead(t + 1)
                     with TraceAnnotation("svi.elbo_sync"):
                         elbo_f = float(elbo)
                     history["elbo"].append(elbo_f)
@@ -1156,6 +1209,9 @@ class SVI:
                     if callback is not None and callback(t, elbo_f) is False:
                         break
         finally:
+            if self._staged is not None:     # past the end, a stop, an error
+                self.staged_dropped += 1
+                self._staged = None
             if store is not None:
                 store.wait()
         return state, history

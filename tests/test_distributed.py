@@ -35,6 +35,10 @@ def test_svi_outofcore_parity(dist_output):
     assert "PASS svi_outofcore_parity" in dist_output
 
 
+def test_svi_staging_plan_bitwise(dist_output):
+    assert "PASS svi_staging_plan" in dist_output
+
+
 def test_vmp_collectives(dist_output):
     assert "PASS vmp_collectives" in dist_output
 

@@ -280,3 +280,221 @@ def test_nytimes_scorer_leaves_streamed_kernel(monkeypatch):
         {"arrays": arrays, "dirs": dirs}, jnp.float32(0.5),
         jnp.float32(2.0)).jaxpr))
     assert [e for e in eqns if e.primitive.name == "pallas_call"]
+
+
+# ---------------------------------------------------------------------------
+# staged inputs: fit() puts step t+1's batch on the device while step t runs
+# ---------------------------------------------------------------------------
+
+def _staging_engine(case, small_corpus, tmp_path):
+    """A factory of fresh, identically configured engines for ``case``:
+    resident LDA, SLDA (a segment latent) or LDA over a sharded corpus
+    (the prefetch thread's path)."""
+    cfg = SVIConfig(batch_size=8, pad_multiple=32, holdout_frac=0.1,
+                    holdout_every=3, seed=2)
+    if case == "lda":
+        prog = models.make("lda", alpha=0.1, beta=0.05, K=3, V=30)
+        prog["x"].observe(small_corpus["tokens"],
+                          segment_ids=small_corpus["doc_ids"])
+        prog = prog.compile()
+        return lambda: SVI(prog, cfg)
+    if case == "slda":
+        prog = _slda_program(small_corpus)
+        return lambda: SVI(prog, cfg)
+    from repro.data import write_sharded_corpus
+    store = write_sharded_corpus(small_corpus, str(tmp_path / "c"),
+                                 shard_tokens=500)
+    return lambda: SVI(models.make("lda", alpha=0.1, beta=0.05, K=3, V=30),
+                       cfg, corpus=store)
+
+
+def _plain_fit(svi, steps, state):
+    """fit()'s loop as it ran before staging: ``step(t)``, ``float(elbo)``
+    and the held-out score, one after the other."""
+    hist = {"elbo": [], "heldout": []}
+    start = int(state.step)
+    for t in range(start, start + steps):
+        state, elbo = svi.step(t, state)
+        hist["elbo"].append(float(elbo))
+        if (t + 1) % svi.cfg.holdout_every == 0 or t == start + steps - 1:
+            hist["heldout"].append((t, svi.heldout_elbo(state)))
+    return state, hist
+
+
+def _assert_bitwise(a, b):
+    assert int(a.step) == int(b.step)
+    for n in a.posteriors:
+        np.testing.assert_array_equal(np.asarray(a.posteriors[n]),
+                                      np.asarray(b.posteriors[n]))
+
+
+STAGING_CASES = ["lda", "slda", "lda-sharded"]
+
+
+@pytest.mark.parametrize("case", STAGING_CASES)
+def test_staged_fit_is_bitwise_plain_loop(case, small_corpus, tmp_path):
+    """fit() with staging gives the plain loop's history and state, bit for
+    bit, dispatches every step from staged inputs, and drops the inputs it
+    staged past its last step."""
+    make = _staging_engine(case, small_corpus, tmp_path)
+    svi = make()
+    seen = []
+    state, hist = svi.fit(steps=8, callback=lambda t, e: seen.append((t, e)))
+    svi.close()
+    assert (svi.staged_used, svi.staged_dropped) == (8, 1)
+    assert svi._staged is None
+    assert seen == list(zip(range(8), hist["elbo"]))
+
+    plain = make()
+    want_state, want = _plain_fit(plain, 8, init_state(plain.program, 2))
+    plain.close()
+    assert (plain.staged_used, plain.staged_dropped) == (0, 0)
+    assert hist == want
+    _assert_bitwise(state, want_state)
+
+
+@pytest.mark.parametrize("case", STAGING_CASES)
+def test_stop_drops_staged_batch_and_resumes_bitwise(case, small_corpus,
+                                                     tmp_path):
+    """A callback that stops at t gets the state after step t; the batch
+    staged for t+1 is dropped, and a later fit from there lands on the
+    uninterrupted run."""
+    make = _staging_engine(case, small_corpus, tmp_path)
+    whole = make()
+    want, want_hist = whole.fit(steps=9)
+    whole.close()
+
+    svi = make()
+    state, h1 = svi.fit(steps=9, callback=lambda t, e: t != 4 and None)
+    assert int(state.step) == 5 and len(h1["elbo"]) == 5
+    assert (svi.staged_used, svi.staged_dropped) == (5, 1)
+    assert svi._staged is None
+    state, h2 = svi.fit(steps=4, state=state)
+    svi.close()
+    assert (svi.staged_used, svi.staged_dropped) == (9, 2)
+    assert h1["elbo"] + h2["elbo"] == want_hist["elbo"]
+    _assert_bitwise(state, want)
+
+
+@pytest.mark.parametrize("x", [0.5, 1e-3, 312.5])
+def test_step_scalar_has_jnp_float32_aval(lda_program, x):
+    """A step scalar made while staging (a copied ``np.float32``) has the
+    aval and weak type of ``jnp.float32(x)``, so a jit called with either
+    compiles once."""
+    svi = SVI(lda_program, SVIConfig(batch_size=8, seed=2))
+    got, want = svi._scalar(x), jnp.float32(x)
+    assert jax.typeof(got) == jax.typeof(want)
+    assert jax.typeof(got).weak_type is jax.typeof(want).weak_type is False
+    assert float(got) == float(want)
+    fn = jax.jit(lambda a, b: a * b)
+    fn(jnp.ones(3), want)
+    fn(jnp.ones(3), got)
+    assert fn._cache_size() == 1
+
+
+def test_staged_fit_compiles_nothing_after_warm_steps(lda_program):
+    """After warm steps over every batch of an epoch (the same batches in
+    every epoch) and one held-out score, a staged fit adds no step
+    signature and compiles nothing: its scalars have the avals the warm
+    steps' had."""
+    svi = SVI(lda_program, SVIConfig(batch_size=8, pad_multiple=32,
+                                     holdout_frac=0.1, holdout_every=3,
+                                     shuffle=False, seed=2))
+    state = init_state(svi.program, 2)
+    for t in range(svi.sampler.batches_per_epoch):
+        state, _ = svi.step(t, state)
+    svi.heldout_elbo(state)
+    sigs = dict(svi._steps)
+    compiles = []
+
+    def on_compile(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        state, _ = svi.fit(steps=2 * svi.sampler.batches_per_epoch,
+                           state=state)
+        jax.block_until_ready(state)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles == []
+    assert svi._steps == sigs
+    assert all(fn._cache_size() == 1 for fn in sigs.values())
+
+
+@pytest.mark.parametrize("stop", [False, True])
+def test_staging_error_raises_at_its_own_step(lda_program, stop):
+    """A batch that fails to build while staged is raised by the step that
+    would have built it, after the callback saw the step before; a stop
+    there drops it unraised."""
+    svi = SVI(lda_program, SVIConfig(batch_size=8, pad_multiple=32, seed=2))
+    bad = svi.sampler.batch_at(3)
+    load = svi._load_groups
+
+    def failing(groups):
+        if np.array_equal(groups, bad):
+            raise OSError("shard unreadable")
+        return load(groups)
+
+    svi._load_groups = failing
+    seen = []
+
+    def callback(t, _elbo):
+        seen.append(t)
+        return False if stop and t == 2 else None
+
+    if stop:
+        state, _ = svi.fit(steps=6, callback=callback)
+        assert int(state.step) == 3
+    else:
+        with pytest.raises(OSError, match="unreadable"):
+            svi.fit(steps=6, callback=callback)
+    assert seen == [0, 1, 2]
+    assert svi._staged is None and svi.staged_dropped == int(stop)
+
+
+def test_fit_spans_stage_between_dispatch_and_sync(small_corpus, tmp_path):
+    """In a profiled fit the first step's inputs are staged before its
+    step span, and each step span holds the dispatch of step t, then
+    ``svi.stage`` (step t+1's host batch, prefetch wait and copy to the
+    device), then the wait for step t's ELBO: one of each in every span,
+    the last included."""
+    import glob
+
+    make = _staging_engine("lda-sharded", small_corpus, tmp_path)
+    svi = make()
+    with jax.profiler.trace(str(tmp_path / "profile")):
+        svi.fit(steps=4)
+    svi.close()
+    path = sorted(glob.glob(f"{tmp_path}/profile/**/*.xplane.pb",
+                            recursive=True))[-1]
+    main = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(ev.name.split("#")[0], ev.start_ns,
+                    ev.start_ns + ev.duration_ns) for ev in line.events]
+            if any(n == "svi.step" for n, _, _ in evs):
+                main = evs
+    steps = [ev for ev in main if ev[0] == "svi.step"]
+    assert len(steps) == 4
+
+    def inside(outer, name):
+        return [ev for ev in main if ev[0] == name
+                and outer[1] <= ev[1] and ev[2] <= outer[2]]
+
+    def staged_once(stage):
+        for name in ("svi.host_batch", "store.batch_wait", "svi.device_put"):
+            assert len(inside(stage, name)) == 1, name
+
+    first = [ev for ev in main if ev[0] == "svi.stage"
+             and ev[2] <= steps[0][1]]
+    assert len(first) == 1
+    staged_once(first[0])
+    for st in steps:
+        (dispatch,), (stage,), (sync,) = (
+            inside(st, name) for name in ("svi.dispatch", "svi.stage",
+                                          "svi.elbo_sync"))
+        assert dispatch[2] <= stage[1] and stage[2] <= sync[1]
+        staged_once(stage)
+        assert len(inside(st, "svi.host_batch")) == 1
